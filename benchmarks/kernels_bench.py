@@ -15,8 +15,8 @@ import jax.numpy as jnp
 from benchmarks.common import emit, time_op
 from repro.kernels.ops import merge_sorted_runs, topk_smallest, windowed_merge
 
-PALLAS8 = "interpret@rows_per_block=8"
-PALLAS4 = "interpret@rows_per_block=4"
+TOPK_ARM = "interpret"
+MERGE_ARM = "interpret@rows_per_block=8"
 
 
 def run(quick: bool = False):
@@ -27,7 +27,7 @@ def run(quick: bool = False):
         vals = jnp.asarray(np.tile(np.arange(N, dtype=np.int32), (R, 1)))
         t_ref = time_op(lambda a, b: topk_smallest(a, b, k, arm="ref"),
                         keys, vals, iters=5)
-        t_ker = time_op(lambda a, b: topk_smallest(a, b, k, arm=PALLAS8),
+        t_ker = time_op(lambda a, b: topk_smallest(a, b, k, arm=TOPK_ARM),
                         keys, vals, iters=3)
         # compare-op accounting: kernel O(N log k) vs full-sort O(N log^2 N)
         ops_kernel = N * (math.log2(k) + 1)
@@ -49,7 +49,7 @@ def run(quick: bool = False):
         jnp.asarray(buf_k), jnp.asarray(run_k), iters=5,
     )
     t_ker = time_op(
-        lambda a, b: merge_sorted_runs(a, zeros_c, b, zeros_r, arm=PALLAS4),
+        lambda a, b: merge_sorted_runs(a, zeros_c, b, zeros_r, arm=MERGE_ARM),
         jnp.asarray(buf_k), jnp.asarray(run_k), iters=3,
     )
     ops_bitonic = 2 * C * (math.log2(2 * C))
@@ -74,7 +74,7 @@ def run(quick: bool = False):
     )
     t_ker = time_op(
         lambda a, b: windowed_merge(a, zeros_h, zeros_h, b, zeros_r2, zeros_r2,
-                                    arm=PALLAS4),
+                                    arm=MERGE_ARM),
         jnp.asarray(head_k), jnp.asarray(wrun_k), iters=3,
     )
     w = H + Rw2

@@ -15,6 +15,9 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 
 def main() -> None:
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="reduced sweep sizes (CI mode)")

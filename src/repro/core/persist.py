@@ -132,9 +132,7 @@ def atomic_savez(path: Path | str, *, compressed: bool = False,
 
 
 def flatten_with_paths(tree) -> Tuple[List[str], list, Any]:
-    # jax.tree.flatten_with_path is a late alias of
-    # jax.tree_util.tree_flatten_with_path — use the long-lived spelling.
-    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     paths = ["/".join(str(k) for k in path) for path, _ in flat]
     leaves = [leaf for _, leaf in flat]
     return paths, leaves, treedef

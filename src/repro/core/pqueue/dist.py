@@ -61,18 +61,10 @@ class AxisCfg:
         return ((self.pod_axis,) if self.pod_axis else ()) + tuple(self.shard_axes)
 
 
-def _one_axis_size(a: str) -> int:
-    # jax.lax.axis_size is a late addition; psum of the literal 1 is the
-    # long-lived spelling and folds to a static Python int inside shard_map.
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(a)
-    return jax.lax.psum(1, a)
-
-
 def _axis_size(axes: Sequence[str]) -> int:
     n = 1
     for a in axes:
-        n *= _one_axis_size(a)
+        n *= jax.lax.axis_size(a)
     return n
 
 
@@ -80,7 +72,7 @@ def _device_rank(axes: Sequence[str]) -> jnp.ndarray:
     """Row-major rank over the given axes."""
     rank = jnp.int32(0)
     for a in axes:
-        rank = rank * _one_axis_size(a) + jax.lax.axis_index(a)
+        rank = rank * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return rank
 
 

@@ -27,10 +27,8 @@ from repro.core.pqueue.state import INF_KEY, PQState
 _INT32_MIN = jnp.iinfo(jnp.int32).min
 _INT32_MAX = jnp.iinfo(jnp.int32).max
 
-# Static width of the tail's unsorted append bucket.  When a shard's bucket
-# would outgrow it, the cond-guarded compaction sorts the BUCKET only
-# (O(U log U), U static) and rank-merges it into the leading sorted run
-# (O(T)) — replacing the old full O(T log T) tail sort on every refill.
+# Width the tail's unsorted append bucket may reach before the cond-guarded
+# compaction (`compact_tail`) sorts the tail window again.
 TAIL_BUCKET_WIDTH = 256
 
 # Renumber horizon: force a rebalance (which renumbers seqs positionally)
@@ -44,11 +42,12 @@ SEQ_RENUMBER_THRESHOLD = _INT32_MAX - (1 << 24)
 # `registry.force_arms`) to pin a specific arm in tests/benchmarks.
 
 
-def _key_seq_order(keys: jnp.ndarray, seq: jnp.ndarray) -> jnp.ndarray:
-    """Row-wise argsort by (key, seq) lexicographic — the stable
-    linearization order.  (x64 is disabled in this container, so the order
-    is two chained stable sorts rather than a packed int64 key.)"""
-    return jnp.lexsort((seq, keys), axis=1)
+def _key_seq_sort(keys, seq, vals):
+    """Row-wise (key, seq)-lexicographic sort — the linearization order —
+    carrying vals along: one multi-operand sort, no gathers.  (A TPU
+    gather over the (S, T) arena costs about 1 s per 64M slots on a v5e,
+    a sort of the same slots about 0.15 s.)  Returns (keys, seq, vals)."""
+    return jax.lax.sort((keys, seq, vals), dimension=1, num_keys=2)
 
 
 # ---------------------------------------------------------------------------
@@ -233,7 +232,7 @@ def remove_at(
 
 
 # ---------------------------------------------------------------------------
-# bucketed tail arena: sorted run + append bucket, merge-on-rebalance
+# bucketed tail arena: sorted run + append bucket, sort-on-rebalance
 # ---------------------------------------------------------------------------
 
 
@@ -250,8 +249,8 @@ def _renumber_seqs(
     sorted) — exactly the state every rebalance sort produces.  Then
     ``head slot i -> seq i`` and ``tail slot j -> seq head_size + j``
     preserves every relative (key, seq) comparison while resetting
-    ``next_seq`` to the shard population.  Side effect the bucket merge
-    relies on: the sorted run's seq column becomes globally ascending."""
+    ``next_seq`` to the shard population.  Side effect: the sorted run's
+    seq column becomes globally ascending."""
     S, H = head_seq.shape
     T = tail_seq.shape[1]
     col_h = jnp.arange(H, dtype=jnp.int32)[None, :]
@@ -266,29 +265,28 @@ def _renumber_seqs(
     return new_hq, new_tq, (head_size + tail_size).astype(jnp.int32)
 
 
-def _tail_window(state: PQState):
-    """Masked (key, val, seq) views of the tail's sliding window: stale
+def _tail_window_kqv(state: PQState):
+    """Masked (key, seq, val) views of the tail's sliding window: stale
     out-of-window slots read (INF, 0, 0).  The validity predicate is owned
     by `PQState._tail_window_mask` (shared with the keys/vals views and the
     invariant checker)."""
     win = state._tail_window_mask()
     return (
         jnp.where(win, state.tail_keys, INF_KEY),
-        jnp.where(win, state.tail_vals, 0),
         jnp.where(win, state.tail_seq, 0),
+        jnp.where(win, state.tail_vals, 0),
     )
 
 
-def _full_sort_tail(state: PQState) -> PQState:
-    """Fallback compaction: (key, seq)-lex sort of the tail window, then
-    renumber; the window re-anchors at 0.  O(T log T) — taken only when the
-    append bucket exceeded its static width (batches wider than
-    TAIL_BUCKET_WIDTH)."""
-    wk, wv, wq = _tail_window(state)
-    order = _key_seq_order(wk, wq)
-    tk = jnp.take_along_axis(wk, order, axis=1)
-    tv = jnp.take_along_axis(wv, order, axis=1)
-    tq = jnp.take_along_axis(wq, order, axis=1)
+def compact_tail(state: PQState) -> PQState:
+    """Make the tail fully sorted (tail_sorted == tail_size) and renumber
+    seqs: one (key, seq)-lex sort of the tail window, which re-anchors at
+    0.  O(T log T); callers cond-guard the invocation (it fires when the
+    append bucket would outgrow TAIL_BUCKET_WIDTH, and on refills that
+    find appends since the last compaction)."""
+    if state.tail_width == 0:
+        return state
+    tk, tq, tv = _key_seq_sort(*_tail_window_kqv(state))
     hq, tq, nseq = _renumber_seqs(
         state.head_seq, tq, state.head_size, state.tail_size
     )
@@ -297,95 +295,6 @@ def _full_sort_tail(state: PQState) -> PQState:
         tail_start=jnp.zeros_like(state.tail_start),
         tail_sorted=state.tail_size, next_seq=nseq,
     )
-
-
-def _bucket_merge_tail(state: PQState) -> PQState:
-    """Sort the append bucket and rank-merge it into the sorted run.
-
-    Cost per shard row: O(U log U) for the bucket sort (U = static
-    TAIL_BUCKET_WIDTH) + O(T + U log T) for the merge — the O(T) tail
-    rebalance the ROADMAP asked for.  The lexicographic (key, seq) merge
-    needs no packed 64-bit keys: the run's seq column is globally ascending
-    (renumbering invariant), so the count of run elements lex-below a bucket
-    element is ``clip(ss(run.seq, b.seq), ss(run.key, b.key, L),
-    ss(run.key, b.key, R))`` — three searchsorteds."""
-    S, T = state.tail_keys.shape
-    U = min(T, TAIL_BUCKET_WIDTH)
-    a_len = state.tail_sorted  # (S,) sorted-run lengths
-    b_len = state.tail_size - a_len  # (S,) bucket lengths, <= U (guarded)
-    t0 = state.tail_start
-    col_t = jnp.arange(T, dtype=jnp.int32)[None, :]
-    col_u = jnp.arange(U, dtype=jnp.int32)[None, :]
-    row = jnp.arange(S, dtype=jnp.int32)[:, None]
-
-    # -- extract + lex-sort the bucket (window offset t0 + a_len) ------------
-    gidx = jnp.clip(t0[:, None] + a_len[:, None] + col_u, 0, T - 1)
-    b_valid = col_u < b_len[:, None]
-    bk = jnp.where(b_valid, jnp.take_along_axis(state.tail_keys, gidx, axis=1),
-                   INF_KEY)
-    bv = jnp.where(b_valid, jnp.take_along_axis(state.tail_vals, gidx, axis=1),
-                   0)
-    bq = jnp.where(b_valid, jnp.take_along_axis(state.tail_seq, gidx, axis=1),
-                   _INT32_MAX)
-    order = _key_seq_order(bk, bq)
-    bk = jnp.take_along_axis(bk, order, axis=1)
-    bv = jnp.take_along_axis(bv, order, axis=1)
-    bq = jnp.take_along_axis(bq, order, axis=1)
-
-    # -- 0-aligned view of the sorted run (gather from the window) -----------
-    a_idx = jnp.clip(t0[:, None] + col_t, 0, T - 1)
-    a_valid = col_t < a_len[:, None]
-    ak = jnp.where(
-        a_valid, jnp.take_along_axis(state.tail_keys, a_idx, axis=1), INF_KEY
-    )
-    av = jnp.where(
-        a_valid, jnp.take_along_axis(state.tail_vals, a_idx, axis=1), 0
-    )
-    aq = jnp.where(
-        a_valid, jnp.take_along_axis(state.tail_seq, a_idx, axis=1),
-        _INT32_MAX,
-    )  # ascending overall
-
-    # -- lexicographic ranks of bucket elements in the run -------------------
-    lo = jax.vmap(lambda a, b: jnp.searchsorted(a, b, side="left"))(ak, bk)
-    hi = jax.vmap(lambda a, b: jnp.searchsorted(a, b, side="right"))(ak, bk)
-    sq = jax.vmap(lambda a, b: jnp.searchsorted(a, b, side="left"))(aq, bq)
-    pos_b = jnp.clip(sq, lo, hi).astype(jnp.int32) + col_u  # (S, U)
-
-    # -- scatter bucket, fill run into the complement slots ------------------
-    occ = jnp.zeros((S, T), jnp.int32).at[row, pos_b].set(1, mode="drop")
-    sk = jnp.full((S, T), INF_KEY, jnp.int32).at[row, pos_b].set(bk, mode="drop")
-    sv = jnp.zeros((S, T), jnp.int32).at[row, pos_b].set(bv, mode="drop")
-    sq_out = jnp.zeros((S, T), jnp.int32).at[row, pos_b].set(bq, mode="drop")
-    run_idx = jnp.clip(col_t - jnp.cumsum(occ, axis=1), 0, T - 1)
-    is_b = occ == 1
-    mk = jnp.where(is_b, sk, jnp.take_along_axis(ak, run_idx, axis=1))
-    mv = jnp.where(is_b, sv, jnp.take_along_axis(av, run_idx, axis=1))
-    mq = jnp.where(is_b, sq_out, jnp.take_along_axis(aq, run_idx, axis=1))
-
-    out_valid = col_t < state.tail_size[:, None]
-    mk = jnp.where(out_valid, mk, INF_KEY)
-    mv = jnp.where(out_valid, mv, 0)
-    mq = jnp.where(out_valid, mq, 0)
-    hq, mq, nseq = _renumber_seqs(
-        state.head_seq, mq, state.head_size, state.tail_size
-    )
-    return dataclasses.replace(
-        state, tail_keys=mk, tail_vals=mv, tail_seq=mq, head_seq=hq,
-        tail_start=jnp.zeros_like(state.tail_start),
-        tail_sorted=state.tail_size, next_seq=nseq,
-    )
-
-
-def compact_tail(state: PQState) -> PQState:
-    """Make the tail fully sorted (tail_sorted == tail_size) and renumber
-    seqs.  Bucket path when every shard's bucket fits the static window,
-    full-sort fallback otherwise.  Callers cond-guard the invocation."""
-    if state.tail_width == 0:
-        return state
-    U = min(state.tail_width, TAIL_BUCKET_WIDTH)
-    fits = jnp.all(state.tail_size - state.tail_sorted <= U)
-    return jax.lax.cond(fits, _bucket_merge_tail, _full_sort_tail, state)
 
 
 # ---------------------------------------------------------------------------
@@ -407,7 +316,7 @@ def tiered_insert(
     keys append to the tail's unsorted bucket in O(batch).  Two cond-guarded
     rebalances cover the rare paths: (a) when a shard's append bucket would
     outgrow its static width — or next_seq nears the int32 wrap — the tail
-    is compacted (bucket sort + O(T) rank merge, seqs renumbered); (b) only
+    is compacted (one window sort, seqs renumbered); (b) only
     when a shard's arena cannot hold the append does the overflow branch run
     a full (key, seq) sort that keeps the C smallest of the union and
     reports the rest in `dropped` — the same semantics the old full-width
@@ -492,10 +401,11 @@ def tiered_insert(
 
     def no_overflow(op):
         tk, tv, tq, tsize = op
-        # Gather append (scatter-free — see merge_head_run): the combined
-        # append run is trun ++ spill (width 2R); tail slot t takes
-        # arun[t - tail_size] when it lands in the append window, else
-        # keeps its value.
+        # Scatter append: the combined append run is trun ++ spill (width
+        # 2R); its lane j < n_append lands in slot tail_start + tail_size
+        # + j and the other lanes drop.  The cost is the batch's, not the
+        # arena's (a gather over the (S, T) arena would cost about 1 s per
+        # 64M slots on a TPU v5e).
         col2 = jnp.arange(2 * R, dtype=jnp.int32)[None, :]
         in_trun = col2 < n_tail_inc[:, None]
         idx_tr = jnp.clip(col2, 0, R - 1)
@@ -508,17 +418,11 @@ def tiered_insert(
                 jnp.take_along_axis(sp_x, idx_sp, axis=1),
             )
 
-        colT = jnp.arange(T, dtype=jnp.int32)[None, :]
-        rel = colT - (state.tail_start + tsize)[:, None]  # window-end slot
-        in_app = (rel >= 0) & (rel < n_append[:, None])
-        rel_c = jnp.clip(rel, 0, 2 * R - 1)
+        dest = jnp.where(col2 < n_append[:, None],
+                         (state.tail_start + tsize)[:, None] + col2, T)
 
         def splice(tail_x, trun_x, sp_x):
-            return jnp.where(
-                in_app,
-                jnp.take_along_axis(arun(trun_x, sp_x), rel_c, axis=1),
-                tail_x,
-            )
+            return tail_x.at[row, dest].set(arun(trun_x, sp_x), mode="drop")
 
         return (
             nh_k, nh_v, nh_q,
@@ -534,14 +438,11 @@ def tiered_insert(
 
     def overflow(op):
         tk, tv, tq, tsize = op
-        wk, wv, wq = _tail_window(state)  # stale slots masked out
+        wk, wq, wv = _tail_window_kqv(state)  # stale slots masked out
         cat_k = jnp.concatenate([nh_k, wk, trun_k, sp_k], axis=1)
         cat_v = jnp.concatenate([nh_v, wv, trun_v, sp_v], axis=1)
         cat_q = jnp.concatenate([nh_q, wq, trun_q, sp_q], axis=1)
-        order = _key_seq_order(cat_k, cat_q)
-        sk = jnp.take_along_axis(cat_k, order, axis=1)[:, : H + T]
-        sv = jnp.take_along_axis(cat_v, order, axis=1)[:, : H + T]
-        sq = jnp.take_along_axis(cat_q, order, axis=1)[:, : H + T]
+        sk, sq, sv = (x[:, : H + T] for x in _key_seq_sort(cat_k, cat_q, cat_v))
         dropped = jnp.maximum(valid_total - (H + T), 0).astype(jnp.int32)
         hsize_new = jnp.minimum(valid_total, H).astype(jnp.int32)
         tsize_new = jnp.clip(valid_total - H, 0, T).astype(jnp.int32)
@@ -618,7 +519,7 @@ def refill_head(state: PQState) -> PQState:
     With the sliding-window tail this CONSUMES the sorted run in place: the
     smallest elements are the run's front (gathered into the head merge),
     and the window origin just advances — the tail arrays are never
-    rewritten.  Cost: O(H) for the merge + O(U log U + T) bucket compaction
+    rewritten.  Cost: O(H) for the merge + one tail-window sort
     only when appends happened since the last rebalance.  `ensure_head`
     inlines this as two separately-guarded conds (see `refill_head_guarded`)
     so the common consume path's cond returns only head-sized buffers."""
@@ -668,8 +569,8 @@ def refill_head_guarded(state: PQState, pred: jnp.ndarray) -> PQState:
 
 # ---------------------------------------------------------------------------
 # legacy full-width merge (kept as the reference for the capacity-wide
-# Pallas kernel in kernels/sorted_merge.py; the insert hot path now uses
-# merge_head_run + tiered_insert)
+# `merge_sorted_runs` kernel; the insert hot path now uses merge_head_run +
+# tiered_insert)
 # ---------------------------------------------------------------------------
 
 

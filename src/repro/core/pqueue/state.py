@@ -22,11 +22,10 @@ that: each shard is split into
     simply ignored; the ``keys``/``vals`` views and the invariant checker
     mask them).  The tail arrays themselves are only rewritten by the rare,
     ``lax.cond``-guarded rebalances: when the bucket would outgrow its
-    static width — or the window would slide off the arena end — the bucket
-    alone is sorted (O(U log U)) and rank-merged into the run (O(T)),
-    re-anchoring the window at 0.  A full O(T log T) tail sort survives only
-    as the fallback for over-wide buckets and the capacity-overflow
-    drop-compaction.
+    static width — or the window would slide off the arena end — one
+    (key, seq) sort of the window (vals carried along, no gathers)
+    re-anchors it at 0; the capacity-overflow drop-compaction is the same
+    sort over head, tail and the incoming run.
 
 Head sizing rule: ``H`` must cover every schedule's per-step draw window —
 ``H >= m + (ilog2(S)+1)**2`` (the spray window bound; exact and MULTIQ
@@ -45,10 +44,9 @@ Per-shard insertion sequence numbers (``head_seq`` / ``tail_seq`` /
 implicitly ordered (stable merges + the strict boundary split guarantee
 equal-key head entries are in seq order, and every equal-key tail entry has
 a larger seq than any head entry), so the hot path never sorts by seq; the
-rare rebalance sorts only the tail's append bucket by ``(key, seq)`` and
-merges it into the sorted run, which is exactly what makes the exact
-schedules bit-identical to the oracle's (key, shard, seq) linearization
-even when elements bounce head -> tail -> head.
+rare rebalance sorts the tail window by ``(key, seq)``, which is exactly
+what makes the exact schedules bit-identical to the oracle's (key, shard,
+seq) linearization even when elements bounce head -> tail -> head.
 
 Every rebalance that produces a fully sorted tail also RENUMBERS the
 shard's seqs positionally (head slot i -> i, tail slot j -> head_size + j;
@@ -57,9 +55,7 @@ shard's seqs positionally (head slot i -> i, tail slot j -> head_size + j;
 bounding ``next_seq`` far below the int32 wrap horizon (a near-wrap guard
 in ``tiered_insert`` forces a rebalance before ~2.1e9 cumulative inserts to
 one shard could overflow the counter) and (b) keeping the sorted run's seq
-column globally ascending, which is what lets the bucket merge compare
-(key, seq) pairs with three plain ``searchsorted`` calls instead of a
-packed-int64 sort (x64 is disabled here).
+column globally ascending.
 
 Invariants (property-tested in tests/test_pqueue_property.py):
   I1  head_keys[s] is ascending for every shard s

@@ -19,24 +19,11 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # sharding-in-types churn: AxisType landed after jax 0.4.x
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto-typed
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 AXIS_POD = "pod"
 AXIS_DATA = "data"
 AXIS_MODEL = "model"
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """axis_types=(Auto,)*n on jax versions that have it, {} otherwise —
-    both spellings mean the same thing (fully Auto-partitioned mesh)."""
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_mesh(
@@ -44,16 +31,14 @@ def make_mesh(
     axes: Tuple[str, ...],
     devices=None,
 ) -> Mesh:
-    """Auto-typed mesh (sharding-in-types churn pinned down explicitly)."""
+    """Auto-typed mesh (sharding-in-types pinned down explicitly)."""
+    axis_types = (AxisType.Auto,) * len(axes)
     if devices is not None:
         import numpy as np
 
-        return Mesh(
-            np.asarray(devices).reshape(shape),
-            axes,
-            **_axis_type_kwargs(len(axes)),
-        )
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+        return Mesh(np.asarray(devices).reshape(shape), axes,
+                    axis_types=axis_types)
+    return jax.make_mesh(shape, axes, axis_types=axis_types)
 
 
 def batch_axes(mesh: Mesh) -> Tuple[str, ...]:

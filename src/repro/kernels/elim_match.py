@@ -6,13 +6,12 @@ deleteMins: once the insert log is sorted ascending, the matched set is just
 the prefix below the queue-min cutoff, so the whole match reduces to ONE
 row-wise sort of the (masked) insert keys with their lane tags.
 
-This kernel is that sort: a full bitonic sort of (key, tag) rows, reusing
-the direction-free merge network of `bitonic_topk` (every compare-exchange
-ascending, second run data-flipped — see that module's header for why Mosaic
-wants it this way).  Comparison is lexicographic on (key, tag) with unique
-lane tags, which makes the network bit-identical to a stable argsort — the
-property the exact schedules need so the eliminated prefix matches the
-oracle's (key, batch-position) linearization.
+This kernel is that sort: a full bitonic sort of (key, tag) rows on the
+shared lane-rotation network of `bitonic_topk`.  Comparison is
+lexicographic on (key, tag) with unique lane tags, which makes the network
+bit-identical to a stable argsort — the property the exact schedules need
+so the eliminated prefix matches the oracle's (key, batch-position)
+linearization.
 
 The window engine sorts the whole (K, B) operation log of a K-step window in
 one call (rows = steps) in front of the `lax.scan`; the sort is
@@ -26,41 +25,32 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.bitonic_topk import bitonic_sort
-
-
-def _elim_sort_kernel(keys_ref, tags_ref, out_k_ref, out_t_ref):
-    """Row-block kernel: full ascending sort of (rows, N) (key, tag) pairs."""
-    out_k, out_t = bitonic_sort(keys_ref[...], tags_ref[...])
-    out_k_ref[...] = out_k
-    out_t_ref[...] = out_t
+from repro.kernels.bitonic_topk import (
+    TAG_PAD,
+    bitonic_sort,
+    lane_width,
+    row_blocks_pallas,
+)
 
 
 @functools.partial(jax.jit, static_argnames=("rows_per_block", "interpret"))
 def elim_sort_pallas(
-    keys: jnp.ndarray,  # (R, N) int32, N power of two
+    keys: jnp.ndarray,  # (R, N) int32
     tags: jnp.ndarray,  # (R, N) int32 unique lane tags
     rows_per_block: int = 8,
     interpret: bool = True,
 ):
-    """pallas_call wrapper.  N must be a power of two (ops.py pads with
-    (INF, INT32_MAX) sentinels); R % rows_per_block handled by the caller."""
+    """Row-wise ascending sort of (key, tag) pairs.  Rows are padded to a
+    lane-dense power of two with (INF, INT32_MAX) sentinels, which sort
+    after every real pair (real INF-keyed lanes carry tags < N) and are
+    sliced away."""
     R, N = keys.shape
-    assert N & (N - 1) == 0, f"elim sort needs power-of-two width, got {N}"
-    assert R % rows_per_block == 0, (R, rows_per_block)
-    grid = (R // rows_per_block,)
-
-    spec = pl.BlockSpec((rows_per_block, N), lambda i: (i, 0))
-    return pl.pallas_call(
-        _elim_sort_kernel,
-        grid=grid,
-        in_specs=[spec, spec],
-        out_specs=[spec, spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((R, N), keys.dtype),
-            jax.ShapeDtypeStruct((R, N), tags.dtype),
-        ],
-        interpret=interpret,
-    )(keys, tags)
+    W = lane_width(N)
+    if W != N:
+        keys = jnp.pad(keys, ((0, 0), (0, W - N)),
+                       constant_values=jnp.iinfo(keys.dtype).max)
+        tags = jnp.pad(tags, ((0, 0), (0, W - N)), constant_values=TAG_PAD)
+    out_k, out_t = row_blocks_pallas(bitonic_sort, keys, tags, W,
+                                     rows_per_block, interpret)
+    return out_k[:, :N], out_t[:, :N]

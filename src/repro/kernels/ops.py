@@ -4,8 +4,8 @@ Every wrapper resolves its implementation arm through
 `repro.kernels.registry.resolve` (explicit ``arm=`` > force override >
 tuning-cache winner > safe jnp default; see that module's docstring) and
 then runs a jitted implementation keyed on the resolved arm, so forcing or
-re-tuning an arm never collides with a stale jit cache.  Padding (arbitrary
-N/R/k up to power-of-two network sizes) and dtype plumbing happen here;
+re-tuning an arm never collides with a stale jit cache.  Padding to the
+networks' lane-dense power-of-two widths happens in the Pallas wrappers;
 platform policy (which arms exist where) lives entirely in the registry —
 there is deliberately not a single backend check in this file.
 
@@ -32,23 +32,8 @@ from repro.kernels import registry as REG
 from repro.kernels.bitonic_topk import topk_smallest_pallas
 from repro.kernels.elim_match import elim_sort_pallas
 from repro.kernels.segmin import segment_min_scatter, segment_min_sorted
-from repro.kernels.sorted_merge import merge_sorted_pallas
 from repro.kernels.twochoice import multiq_select_pallas, twochoice_pick_pallas
 from repro.kernels.windowed_merge import windowed_merge_pallas
-
-_INT32_MAX = jnp.iinfo(jnp.int32).max
-
-
-def _next_pow2(n: int) -> int:
-    return 1 << max(n - 1, 0).bit_length() if n > 1 else 1
-
-
-def _rows_per_block(kw: dict, rows: int) -> int:
-    """Clamp an arm's rows_per_block axis down to a divisor of `rows`."""
-    rpb = kw.pop("rows_per_block", 8)
-    while rows % rpb:
-        rpb //= 2
-    return max(rpb, 1)
 
 
 # ---------------------------------------------------------------------------
@@ -62,8 +47,8 @@ def topk_smallest(
     k: int,
     arm: Optional[str] = None,
 ):
-    """k smallest per row, ascending.  Pallas arms pad N up to a multiple
-    of the power-of-two k' >= k with INF sentinels, then slice back."""
+    """k smallest per row, ascending.  Pallas arms pad each row to a
+    lane-dense power of two with sentinels (kernels.bitonic_topk)."""
     coords = {"R": keys.shape[0], "N": keys.shape[1], "k": k,
               "dtype": str(keys.dtype)}
     return _topk_dispatch(keys, vals, k, REG.resolve("topk_smallest",
@@ -78,20 +63,8 @@ def _topk_dispatch(keys, vals, k, arm):
         order = jnp.argsort(keys, axis=-1, stable=True)[..., :k]
         return (jnp.take_along_axis(keys, order, axis=-1),
                 jnp.take_along_axis(vals, order, axis=-1))
-    kw = REG.arm_kwargs("topk_smallest", arm)
-    Rr, N = keys.shape
-    kp = _next_pow2(k)
-    Np = max(_next_pow2(N), kp)
-    if Np % kp:
-        Np = (Np // kp + 1) * kp
-    pad_n = Np - N
-    if pad_n:
-        keys = jnp.pad(keys, ((0, 0), (0, pad_n)), constant_values=INF_KEY)
-        vals = jnp.pad(vals, ((0, 0), (0, pad_n)))
-    out_k, out_v = topk_smallest_pallas(
-        keys, vals, kp, rows_per_block=_rows_per_block(kw, Rr), **kw
-    )
-    return out_k[:, :k], out_v[:, :k]
+    return topk_smallest_pallas(keys, vals, k,
+                                **REG.arm_kwargs("topk_smallest", arm))
 
 
 # ---------------------------------------------------------------------------
@@ -105,9 +78,7 @@ def elim_sort(
     arm: Optional[str] = None,
 ):
     """Row-wise full ascending sort of (key, tag) pairs — the elimination
-    match pre-pass.  Pallas arms pad B up to a power of two with
-    (INF, INT32_MAX) sentinels (real INF-keyed lanes carry tags < B, so
-    they sort before the pads and survive the slice-back)."""
+    match pre-pass."""
     coords = {"R": keys.shape[0], "B": keys.shape[1]}
     return _elim_dispatch(keys, tags, REG.resolve("elim_sort", coords, arm))
 
@@ -120,17 +91,7 @@ def _elim_dispatch(keys, tags, arm):
         order = jnp.argsort(keys, axis=1, stable=True).astype(jnp.int32)
         return (jnp.take_along_axis(keys, order, axis=1),
                 jnp.take_along_axis(tags, order, axis=1))
-    kw = REG.arm_kwargs("elim_sort", arm)
-    Rr, B = keys.shape
-    Bp = _next_pow2(B)
-    if Bp != B:
-        keys = jnp.pad(keys, ((0, 0), (0, Bp - B)), constant_values=INF_KEY)
-        tags = jnp.pad(tags, ((0, 0), (0, Bp - B)),
-                       constant_values=_INT32_MAX)
-    out_k, out_t = elim_sort_pallas(
-        keys, tags, rows_per_block=_rows_per_block(kw, Rr), **kw
-    )
-    return out_k[:, :B], out_t[:, :B]
+    return elim_sort_pallas(keys, tags, **REG.arm_kwargs("elim_sort", arm))
 
 
 # ---------------------------------------------------------------------------
@@ -184,14 +145,9 @@ def _multiq_dispatch(win_k, win_v, take, arm):
     if arm == "ref":
         out_k, out_t = R.multiq_select_ref(win_k, tags, take)
     else:
-        kw = REG.arm_kwargs("multiq_select_topm", arm)
-        mp = _next_pow2(m)
-        pk, pt = win_k, tags
-        if mp != m:
-            pk = jnp.pad(pk, ((0, 0), (0, mp - m)), constant_values=INF_KEY)
-            pt = jnp.pad(pt, ((0, 0), (0, mp - m)),
-                         constant_values=_INT32_MAX)
-        out_k, out_t = multiq_select_pallas(pk, pt, take, **kw)
+        out_k, out_t = multiq_select_pallas(
+            win_k, tags, take.astype(jnp.int32),
+            **REG.arm_kwargs("multiq_select_topm", arm))
         out_k, out_t = out_k[0, :m], out_t[0, :m]
     safe_t = jnp.clip(out_t, 0, S * m - 1)
     out_v = jnp.where(out_k < INF_KEY, win_v.ravel()[safe_t], 0)
@@ -245,19 +201,9 @@ def _wmerge_dispatch(head_k, head_v, head_q, run_k, run_v, run_q, arm):
     if arm == "ref":
         out_k, out_t = R.windowed_merge_ref(head_k, head_t, run_k, run_t)
     else:
-        kw = REG.arm_kwargs("windowed_merge", arm)
-        Wp = _next_pow2(W)
-        pad = Wp - W
-        rk = run_k
-        rt = H + jnp.arange(Rw + pad, dtype=jnp.int32)[None, :]
-        rt = jnp.broadcast_to(rt, (S, Rw + pad))
-        if pad:
-            rk = jnp.pad(rk, ((0, 0), (0, pad)), constant_values=INF_KEY)
-        kw["rows_per_block"] = _rows_per_block(
-            {"rows_per_block": kw.get("rows_per_block", 4)}, S
-        )
-        out_k, out_t = windowed_merge_pallas(head_k, head_t, rk, rt, **kw)
-        out_k, out_t = out_k[:, :W], out_t[:, :W]
+        out_k, out_t = windowed_merge_pallas(
+            head_k, head_t, run_k, run_t,
+            **REG.arm_kwargs("windowed_merge", arm))
 
     src_v = jnp.concatenate([head_v, run_v], axis=1)
     src_q = jnp.concatenate([head_q, run_q], axis=1)
@@ -291,21 +237,16 @@ def merge_sorted_runs(
 def _msr_dispatch(buf_k, buf_v, run_k, run_v, arm):
     if arm == "ref":
         return R.merge_sorted_runs_ref(buf_k, buf_v, run_k, run_v)
-    kw = REG.arm_kwargs("merge_sorted_runs", arm)
-    S, C = buf_k.shape
-    Rw = run_k.shape[1]
-    assert Rw <= C, (Rw, C)
-    if Rw < C:
-        # (INF, INT32_MAX) pads are lexicographically largest, which keeps
-        # the flipped run lex-descending — the merge network then matches
-        # the (key, val)-lex reference bit-for-bit even on INF sentinels
-        run_k = jnp.pad(run_k, ((0, 0), (0, C - Rw)), constant_values=INF_KEY)
-        run_v = jnp.pad(run_v, ((0, 0), (0, C - Rw)),
-                        constant_values=_INT32_MAX)
-    kw["rows_per_block"] = _rows_per_block(
-        {"rows_per_block": kw.get("rows_per_block", 4)}, S
-    )
-    return merge_sorted_pallas(buf_k, buf_v, run_k, run_v, **kw)
+    # the windowed-merge network keeps every lane; the capacity merge keeps
+    # the C smallest (the kernel's sentinel pads are lexicographically
+    # largest, so the result matches the (key, val)-lex reference
+    # bit-for-bit even on INF sentinels)
+    C = buf_k.shape[1]
+    assert run_k.shape[1] <= C, (run_k.shape, C)
+    out_k, out_v = windowed_merge_pallas(
+        buf_k, buf_v, run_k, run_v,
+        **REG.arm_kwargs("merge_sorted_runs", arm))
+    return out_k[:, :C], out_v[:, :C]
 
 
 # ---------------------------------------------------------------------------

@@ -15,15 +15,17 @@ cost model (bytes / compare-ops) for the roofline records.
     > force override                        (`force_arms` / REPRO_PQ_KERNEL_ARM)
     > tuning-cache winner                   (`kernels.tuning`, keyed by
                                              backend + jax version + shape)
-    > legacy REPRO_PQ_KERNELS=1             (first Pallas arm available)
-    > the spec's safe default               (a jnp arm — today's behavior
-                                             when no tuning record exists)
+    > the spec's safe default               (a jnp arm — the rule whenever
+                                             no tuning record exists)
 
-Platform awareness lives in `supports_compiled`: compiled (non-interpret)
-Pallas arms are only offered on TPU.  GPU deliberately gets the jnp arms —
-the Mosaic kernels do not lower to Triton, and the old
-``interpret=not _on_tpu()`` rule silently handed GPU the Python-interpreted
-kernel bodies, which is never the fast choice.
+An explicit, forced or tuned arm that is not available on this backend
+raises: nothing falls through to another arm in silence.
+
+Platform awareness lives in `Arm.available`: compiled (non-interpret)
+Pallas arms exist only on TPU (`supports_compiled`), and interpret arms
+exist everywhere BUT the TPU — on the chip the Python-interpreted kernel
+bodies would hide the device.  GPU deliberately gets the jnp arms: the
+Mosaic kernels do not lower to Triton.
 
 Arm naming: ``ref`` / ``argsort`` / ``rank`` / ``scatter`` / ``sorted`` are
 jnp arms; Pallas arms are ``interpret`` / ``compiled`` with tuning-axis
@@ -75,7 +77,7 @@ class Arm:
     """One implementation choice for a kernel.
 
     kind: "jnp" (reference-class, always available), "interpret" (Pallas
-    in interpret mode, always available), "compiled" (Pallas lowered —
+    in interpret mode, available off the TPU), "compiled" (Pallas lowered —
     requires `supports_compiled()`).
     params: static tuning-axis values forwarded to the Pallas wrapper
     (e.g. rows_per_block).  jnp arms carry no params.
@@ -88,6 +90,8 @@ class Arm:
     def available(self, backend: Optional[str] = None) -> bool:
         if self.kind == "compiled":
             return supports_compiled(backend)
+        if self.kind == "interpret":
+            return not supports_compiled(backend)
         return True
 
     @property
@@ -123,8 +127,10 @@ class KernelSpec:
              always a jnp arm, so a missing/corrupt tuning cache can never
              pick a slower-or-unavailable path.
     validation_shapes: coordinate dicts the parity tests sweep (small).
-    tuning_shapes:     coordinate dicts the autotune harness benchmarks
-                       (the hot-path shapes the PQ actually runs).
+    tuning_shapes:     coordinate dicts the autotune harness benchmarks and
+                       the chip smoke run checks: the shapes the queue's
+                       main path runs (64 shards, head width 256, the
+                       TABLE3 replay's 112 x 57 op log).
     make_inputs: (coords, rng) -> (args, static_kwargs) for the wrapper.
     cost_model:  coords -> {"bytes": int, "cmp_ops": float} roofline terms.
     """
@@ -180,14 +186,12 @@ def _parse_force_env() -> None:
 
 _parse_force_env()
 
-# Legacy escape hatch (pre-registry): force the Pallas path everywhere.
-_LEGACY_FORCE_KERNELS = os.environ.get("REPRO_PQ_KERNELS", "") == "1"
-
 
 def set_force_arm(kernel: str, arm: Optional[str]) -> None:
     """Force `kernel` (or "*" for all) to `arm`; None clears the override.
-    An override naming an arm unavailable on this backend is ignored at
-    resolve time (falls through to the default) rather than crashing."""
+    An override naming an arm unavailable on this backend raises at
+    resolve time; a "*" override applies to the kernels that declare the
+    arm."""
     if arm is None:
         _FORCED.pop(kernel, None)
     else:
@@ -212,21 +216,24 @@ def force_arms(mapping: Mapping[str, str]):
 # ---------------------------------------------------------------------------
 
 
-# (kernel, shape sig, arm, source) tuples already reported to telemetry —
-# resolve() runs inside hot dispatch wrappers, so each distinct resolution
-# is noted ONCE per process, not per call.
-_NOTED: set = set()
+# (kernel, shape sig, arm, source) -> shape coords of every resolution
+# already reported to telemetry — resolve() runs inside hot dispatch
+# wrappers (at trace time), so each distinct resolution is noted ONCE per
+# process, not per call.
+RESOLVED: Dict[Tuple[str, str, str, str], Mapping[str, object]] = {}
 
 
-def _note_resolution(name: str, shape_sig: str, arm: str,
+def _note_resolution(name: str, coords: Mapping[str, object], arm: str,
                      source: str) -> str:
-    """Record an arm resolution in the process-global observability bundle
-    (counter + one timeline instant per distinct resolution).  Telemetry
-    must never break dispatch: any obs failure is swallowed."""
+    """Record an arm resolution in `RESOLVED` and the process-global
+    observability bundle (counter + one timeline instant per distinct
+    resolution).  Telemetry must never break dispatch: any obs failure is
+    swallowed."""
+    shape_sig = sig(coords)
     key = (name, shape_sig, arm, source)
-    if key in _NOTED:
+    if key in RESOLVED:
         return arm
-    _NOTED.add(key)
+    RESOLVED[key] = dict(coords)
     try:
         from repro.obs import get_default
 
@@ -244,39 +251,40 @@ def _note_resolution(name: str, shape_sig: str, arm: str,
 def resolve(name: str, coords: Mapping[str, object],
             arm: Optional[str] = None) -> str:
     """The dispatch rule (module docstring).  Returns an arm NAME that is
-    guaranteed available on the current backend.  Every distinct
+    available on the current backend, or raises ValueError when an
+    explicit, forced or tuned choice names one that is not.  Every distinct
     (kernel, shape, arm, source) resolution is noted once in the default
-    observability registry — dispatch decisions are part of the run's
-    telemetry story, not invisible env-dependent magic."""
+    observability registry and in `RESOLVED` — dispatch decisions are part
+    of the run's telemetry story, not invisible env-dependent magic."""
     spec = REGISTRY[name]
     backend = jax.default_backend()
     avail = {a.name for a in spec.arms if a.available(backend)}
     s = sig(coords)
 
-    if arm is not None:  # explicit wins, and must be real
-        if arm not in avail:
+    def chosen(choice: str, source: str) -> str:
+        if choice not in avail:
             raise ValueError(
-                f"{name}: arm {arm!r} is not available on backend "
-                f"{backend!r} (available: {sorted(avail)})"
+                f"{name}: {source} arm {choice!r} is not available on "
+                f"backend {backend!r} (available: {sorted(avail)})"
             )
-        return _note_resolution(name, s, arm, "explicit")
+        return _note_resolution(name, coords, choice, source)
 
-    forced = _FORCED.get(name, _FORCED.get("*"))
-    if forced is not None and forced in avail:
-        return _note_resolution(name, s, forced, "forced")
+    if arm is not None:
+        return chosen(arm, "explicit")
+
+    forced = _FORCED.get(name)
+    if forced is None and _FORCED.get("*") in {a.name for a in spec.arms}:
+        forced = _FORCED["*"]
+    if forced is not None:
+        return chosen(forced, "forced")
 
     from repro.kernels import tuning  # function-level: tuning imports us
 
     winner = tuning.cached_winner(name, s)
-    if winner is not None and winner in avail:
-        return _note_resolution(name, s, winner, "tuned")
+    if winner is not None:
+        return chosen(winner, "tuned")
 
-    if _LEGACY_FORCE_KERNELS:
-        for a in spec.arms:
-            if a.kind != "jnp" and a.name in avail:
-                return _note_resolution(name, s, a.name, "legacy_env")
-
-    return _note_resolution(name, s, spec.default, "default")
+    return _note_resolution(name, coords, spec.default, "default")
 
 
 def arm_kwargs(name: str, arm: str) -> Dict[str, int]:
@@ -476,40 +484,35 @@ REGISTRY: Dict[str, KernelSpec] = {
     for s in (
         _spec(
             "topk_smallest",
-            jnp_arms=("ref", "argsort"), default="argsort",
-            axes={"rows_per_block": (1, 8)},
+            # no row axis: every tournament on the queue's path is one row
+            jnp_arms=("ref", "argsort"), default="argsort", axes={},
             validation=(
                 {"R": 8, "N": 256, "k": 16, "dtype": "int32"},
                 {"R": 3, "N": 100, "k": 7, "dtype": "int32"},
                 {"R": 1, "N": 64, "k": 64, "dtype": "int32"},
                 {"R": 5, "N": 1024, "k": 128, "dtype": "int32"},
+                {"R": 40, "N": 300, "k": 200, "dtype": "int32"},
             ),
             tuning_shapes=(
-                # the deleteMin tournaments the fig9 cast actually runs
-                # (R=1, k=64, N = candidate count per schedule), plus one
-                # batched discriminator where the network's win is large
-                {"R": 1, "N": 1024, "k": 64, "dtype": "int32"},
-                {"R": 1, "N": 1424, "k": 64, "dtype": "int32"},
-                {"R": 1, "N": 512, "k": 64, "dtype": "int32"},
-                {"R": 1, "N": 128, "k": 64, "dtype": "int32"},
-                {"R": 16, "N": 4096, "k": 64, "dtype": "int32"},
+                # the deleteMin tournaments of the S=64, B=57 replay: the
+                # spray removal pool, a HIER pod semifinal (32 shards x m)
+                # and the HIER final (2 pods x m)
+                {"R": 1, "N": 6784, "k": 57, "dtype": "int32"},
+                {"R": 1, "N": 1824, "k": 57, "dtype": "int32"},
+                {"R": 1, "N": 114, "k": 57, "dtype": "int32"},
             ),
             make_inputs=_mk_topk, cost_model=_cost_topk,
         ),
         _spec(
             "elim_sort",
             jnp_arms=("ref", "argsort"), default="argsort",
-            axes={"rows_per_block": (1, 8)},
+            axes={"rows_per_block": (8, 32)},
             validation=(
                 {"R": 1, "B": 16}, {"R": 4, "B": 64}, {"R": 6, "B": 37},
-                {"R": 8, "B": 128},
+                {"R": 8, "B": 128}, {"R": 44, "B": 57},
             ),
-            tuning_shapes=(
-                # the K-step window op-log sort (K rows of B lanes)
-                {"R": 64, "B": 64},
-                {"R": 16, "B": 64},
-                {"R": 256, "B": 64},
-            ),
+            # the fused window's (K, B) op-log sort
+            tuning_shapes=({"R": 112, "B": 57},),
             make_inputs=_mk_elim_sort, cost_model=_cost_elim_sort,
         ),
         _spec(
@@ -518,7 +521,7 @@ REGISTRY: Dict[str, KernelSpec] = {
             validation=(
                 {"S": 4, "m": 16}, {"S": 16, "m": 64}, {"S": 8, "m": 5},
             ),
-            tuning_shapes=({"S": 16, "m": 64},),
+            tuning_shapes=({"S": 64, "m": 57},),
             make_inputs=_mk_twochoice, cost_model=_cost_twochoice,
         ),
         _spec(
@@ -526,34 +529,36 @@ REGISTRY: Dict[str, KernelSpec] = {
             jnp_arms=("ref",), default="ref", axes={},
             validation=(
                 {"S": 4, "m": 16}, {"S": 16, "m": 64}, {"S": 2, "m": 8},
+                {"S": 1, "m": 5}, {"S": 20, "m": 130},
             ),
-            tuning_shapes=({"S": 16, "m": 64},),
+            tuning_shapes=({"S": 64, "m": 57},),
             make_inputs=_mk_multiq_select, cost_model=_cost_multiq_select,
         ),
         _spec(
             "windowed_merge",
             jnp_arms=("ref", "rank"), default="rank",
-            axes={"rows_per_block": (1, 4)},
+            axes={"rows_per_block": (8, 32)},
             validation=(
                 {"S": 4, "H": 64, "R": 16}, {"S": 2, "H": 256, "R": 7},
                 {"S": 6, "H": 100, "R": 60}, {"S": 3, "H": 8, "R": 8},
+                {"S": 36, "H": 64, "R": 20},
             ),
-            tuning_shapes=(
-                # the tiered-insert head merge (H=256 default head tier)
-                {"S": 16, "H": 256, "R": 64},
-                {"S": 16, "H": 256, "R": 256},
-            ),
+            # the tiered-insert head merge (H=256 default head tier, a
+            # routed run of B lanes)
+            tuning_shapes=({"S": 64, "H": 256, "R": 57},),
             make_inputs=_mk_windowed_merge, cost_model=_cost_windowed_merge,
         ),
         _spec(
             "merge_sorted_runs",
             jnp_arms=("ref",), default="ref",
-            axes={"rows_per_block": (1, 4)},
+            axes={"rows_per_block": (8, 32)},
             validation=(
                 {"S": 4, "C": 64, "R": 16}, {"S": 2, "C": 256, "R": 7},
                 {"S": 1, "C": 64, "R": 1},
             ),
-            tuning_shapes=({"S": 8, "C": 1024, "R": 128},),
+            # off the main path since the tiered state; sized like a
+            # head-tier merge
+            tuning_shapes=({"S": 64, "C": 256, "R": 57},),
             make_inputs=_mk_merge_sorted, cost_model=_cost_merge_sorted,
         ),
         _spec(

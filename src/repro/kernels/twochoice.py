@@ -12,15 +12,16 @@ Two kernels back `Schedule.MULTIQ` (the relaxed MultiQueue schedule):
     Mosaic cannot lower for int gathers.
 
   * `multiq_select_pallas`: the commit-side tournament.  Each shard serves
-    its committed lanes from a head-prefix window; the kernel masks the
-    (S, m) windows to the per-shard take counts and reduces them to the m
-    globally-smallest removed pairs, ascending — REUSING
-    `bitonic_merge_topk` from `bitonic_topk` as the inner merge network
-    (same O(S*m log m) compare structure, same lexicographic (key, tag)
+    its committed lanes from a head-prefix window; the wrapper masks the
+    (S, m) windows to the per-shard take counts and reverses the second
+    half of the rows, and the kernel reduces them to the m globally-smallest
+    removed pairs, ascending, in log2(S) rounds of `bitonic_merge_topk`
+    between rows a sublane rotation apart (same lexicographic (key, tag)
     determinism contract as the exact-tournament kernel).
 
 Both follow the repo kernel conventions: jnp references in `kernels.ref`,
-padding/dispatch in `kernels.ops`, interpret mode on CPU.
+padding in the wrappers, dispatch in `kernels.ops`, interpret mode off the
+TPU.
 """
 
 from __future__ import annotations
@@ -30,8 +31,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.bitonic_topk import bitonic_merge_topk
+from repro.kernels.bitonic_topk import TAG_PAD, bitonic_merge_topk, lane_width
 
 INT32_MAX = jnp.iinfo(jnp.int32).max
 
@@ -93,55 +95,60 @@ def twochoice_pick_pallas(
     )[0]
 
 
-def _multiq_select_kernel(win_k_ref, win_v_ref, take_ref, out_k_ref, out_v_ref):
-    """(S, m) head windows + (S, 1) takes -> (1, m) smallest removed pairs."""
-    win_k = win_k_ref[...]  # (S, m)
-    win_v = win_v_ref[...]
-    take = take_ref[...]  # (S, 1)
-    S, m = win_k.shape
-
-    col = jax.lax.broadcasted_iota(jnp.int32, (S, m), 1)
-    mask = col < take  # head-prefix pops only
-    masked_k = jnp.where(mask, win_k, INT32_MAX)
-    masked_v = jnp.where(mask, win_v, INT32_MAX)
-
-    # Each row is already an ascending m-run (sorted shard buffer head;
-    # masking a prefix keeps it ascending — INF holes sort to the tail by
-    # construction), so no per-row sort is needed: fold the S runs straight
-    # through the same bitonic merge network the exact tournament uses.
-    acc_k, acc_v = masked_k[0:1, :], masked_v[0:1, :]
-    for s in range(1, S):
-        acc_k, acc_v = bitonic_merge_topk(
-            acc_k, acc_v, masked_k[s : s + 1, :], masked_v[s : s + 1, :]
-        )
-    out_k_ref[...] = acc_k
-    out_v_ref[...] = acc_v
+def _multiq_select_kernel(x_k_ref, x_t_ref, out_k_ref, out_t_ref):
+    """(Sp, W) masked windows, rows [0, Sp/2) ascending and the rest
+    descending -> (1, W) smallest pairs, ascending.  Each round merges row
+    r with row r + half and leaves rows [0, half) sorted in the directions
+    the next round pairs up; row 0 ends ascending."""
+    keys = x_k_ref[...]
+    tags = x_t_ref[...]
+    Sp = keys.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, keys.shape, 0)
+    half = Sp // 2
+    while half >= 1:
+        pk = pltpu.roll(keys, Sp - half, 0)  # row r meets row r + half
+        pt = pltpu.roll(tags, Sp - half, 0)
+        keys, tags = bitonic_merge_topk(keys, tags, pk, pt,
+                                        asc=row < max(half // 2, 1))
+        half //= 2
+    out_k_ref[...] = keys[0:1, :]
+    out_t_ref[...] = tags[0:1, :]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def multiq_select_pallas(
-    win_k: jnp.ndarray,  # (S, m) head windows, each ascending; m power of two
-    win_v: jnp.ndarray,  # (S, m) position tags (lexicographic determinism)
+    win_k: jnp.ndarray,  # (S, m) head windows, each ascending
+    win_t: jnp.ndarray,  # (S, m) position tags (lexicographic determinism)
     take: jnp.ndarray,  # (S,) int32 commit counts, <= m
     interpret: bool = True,
 ):
-    """m smallest (key, tag) pairs of the masked windows, ascending."""
+    """(1, W) smallest masked (key, tag) pairs ascending, W = lane_width(m);
+    the first m lanes are the result."""
     S, m = win_k.shape
-    assert m & (m - 1) == 0, f"multiq_select needs power-of-two m, got {m}"
+    W = lane_width(m)
+    Sp = max(8, 1 << max(S - 1, 0).bit_length())
+    key_pad = jnp.iinfo(win_k.dtype).max
+    live = jnp.arange(W, dtype=jnp.int32)[None, :] < take[:, None]
+    x_k = jnp.where(live, jnp.pad(win_k, ((0, 0), (0, W - m))), key_pad)
+    x_t = jnp.where(live, jnp.pad(win_t, ((0, 0), (0, W - m))), TAG_PAD)
+    x_k = jnp.pad(x_k, ((0, Sp - S), (0, 0)), constant_values=key_pad)
+    x_t = jnp.pad(x_t, ((0, Sp - S), (0, 0)), constant_values=TAG_PAD)
+    second_half = jnp.arange(Sp)[:, None] >= Sp // 2
+    x_k = jnp.where(second_half, jnp.flip(x_k, axis=1), x_k)
+    x_t = jnp.where(second_half, jnp.flip(x_t, axis=1), x_t)
     return pl.pallas_call(
         _multiq_select_kernel,
         in_specs=[
-            pl.BlockSpec((S, m), lambda: (0, 0)),
-            pl.BlockSpec((S, m), lambda: (0, 0)),
-            pl.BlockSpec((S, 1), lambda: (0, 0)),
+            pl.BlockSpec((Sp, W), lambda: (0, 0)),
+            pl.BlockSpec((Sp, W), lambda: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, m), lambda: (0, 0)),
-            pl.BlockSpec((1, m), lambda: (0, 0)),
+            pl.BlockSpec((1, W), lambda: (0, 0)),
+            pl.BlockSpec((1, W), lambda: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((1, m), win_k.dtype),
-            jax.ShapeDtypeStruct((1, m), win_v.dtype),
+            jax.ShapeDtypeStruct((1, W), win_k.dtype),
+            jax.ShapeDtypeStruct((1, W), win_t.dtype),
         ],
         interpret=interpret,
-    )(win_k, win_v, take.reshape(S, 1).astype(jnp.int32))
+    )(x_k, x_t)

@@ -1,19 +1,23 @@
 """Windowed head-merge Pallas kernel — the tiered insert path's hot spot.
 
 Merges an ascending incoming run (R wide, INF-padded) into each shard's
-ascending head tier (H wide) producing the FULL (S, H+R) merged window —
-unlike `sorted_merge.py` (which keeps the capacity-C smallest and drops the
-rest), nothing is dropped here: the caller takes the first H columns as the
-new hot tier and appends the suffix (the spill — necessarily the largest
+ascending head tier (H wide) producing the FULL (S, H+R) merged window:
+nothing is dropped here.  The caller takes the first H columns as the new
+hot tier and appends the suffix (the spill — necessarily the largest
 elements) to the cold tail arena.  H and R are static and batch-sized, so
 the network cost is O((H+R) log(H+R)) per shard row, independent of the
-queue capacity.
+queue capacity.  The capacity-wide `merge_sorted_runs` reuses the same
+kernel and keeps the first C columns.
 
-Same TPU adaptation as `sorted_merge.py`:
+TPU adaptation: a CPU/GPU merge walks two pointers (data-dependent control
+flow — hostile to the VPU) or rank-scatters (dynamic scatter — hostile to
+Mosaic).  Instead the wrapper lays out
 
-    concat(head_asc, reverse(run_asc))  is bitonic (H+R wide)
-    -> log2(H+R) static clean stages sort it ascending
-    -> ALL H+R lanes are the merge result.
+    concat(head_asc, sentinels, reverse(run_asc))   bitonic, W lanes
+
+(the reversal happens here, outside the kernel) and the kernel runs one
+`clean_bitonic` over the lane-dense row block: all W lanes are the merge
+result.
 
 Comparison is lexicographic on (key, position-tag) — see kernels/ref.py —
 which makes the network's tie resolution identical to the positional-stable
@@ -27,25 +31,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.bitonic_topk import clean_bitonic
-
-
-def _wmerge_kernel(head_k_ref, head_t_ref, run_k_ref, run_t_ref,
-                   out_k_ref, out_t_ref):
-    """Row-block kernel: head (rows, H) + run (rows, R) -> merged
-    (rows, H+R) ascending (full merge, nothing dropped)."""
-    head_k = head_k_ref[...]
-    head_t = head_t_ref[...]
-    run_k = run_k_ref[...]
-    run_t = run_t_ref[...]
-
-    cat_k = jnp.concatenate([head_k, jnp.flip(run_k, axis=-1)], axis=-1)
-    cat_t = jnp.concatenate([head_t, jnp.flip(run_t, axis=-1)], axis=-1)
-    merged_k, merged_t = clean_bitonic(cat_k, cat_t)
-    out_k_ref[...] = merged_k
-    out_t_ref[...] = merged_t
+from repro.kernels.bitonic_topk import (
+    TAG_PAD,
+    clean_bitonic,
+    lane_width,
+    row_blocks_pallas,
+)
 
 
 @functools.partial(jax.jit, static_argnames=("rows_per_block", "interpret"))
@@ -54,31 +46,18 @@ def windowed_merge_pallas(
     head_t: jnp.ndarray,  # (S, H) position tags
     run_k: jnp.ndarray,  # (S, R) ascending, INF-padded
     run_t: jnp.ndarray,  # (S, R) position tags
-    rows_per_block: int = 4,
+    rows_per_block: int = 8,
     interpret: bool = True,
 ):
-    """pallas_call wrapper.  H+R must be a power of two (ops.py pads the run
-    up); returns the full (S, H+R) merged (key, tag) window."""
+    """Full (S, H+R) merged (key, tag) window, ascending."""
     S, H = head_k.shape
     R = run_k.shape[1]
-    W = H + R
-    assert W & (W - 1) == 0, f"window H+R must be a power of two, got {W}"
-    while S % rows_per_block:
-        rows_per_block //= 2
-    rows_per_block = max(rows_per_block, 1)
-    grid = (S // rows_per_block,)
-
-    spec_h = pl.BlockSpec((rows_per_block, H), lambda i: (i, 0))
-    spec_r = pl.BlockSpec((rows_per_block, R), lambda i: (i, 0))
-    spec_o = pl.BlockSpec((rows_per_block, W), lambda i: (i, 0))
-    return pl.pallas_call(
-        _wmerge_kernel,
-        grid=grid,
-        in_specs=[spec_h, spec_h, spec_r, spec_r],
-        out_specs=[spec_o, spec_o],
-        out_shape=[
-            jax.ShapeDtypeStruct((S, W), head_k.dtype),
-            jax.ShapeDtypeStruct((S, W), head_t.dtype),
-        ],
-        interpret=interpret,
-    )(head_k, head_t, run_k, run_t)
+    W = lane_width(H + R)
+    pad = ((0, 0), (0, W - H - R))
+    run_k = jnp.pad(run_k, pad, constant_values=jnp.iinfo(run_k.dtype).max)
+    run_t = jnp.pad(run_t, pad, constant_values=TAG_PAD)
+    cat_k = jnp.concatenate([head_k, jnp.flip(run_k, axis=1)], axis=1)
+    cat_t = jnp.concatenate([head_t, jnp.flip(run_t, axis=1)], axis=1)
+    out_k, out_t = row_blocks_pallas(clean_bitonic, cat_k, cat_t, W,
+                                     rows_per_block, interpret)
+    return out_k[:, :H + R], out_t[:, :H + R]

@@ -41,7 +41,7 @@ from repro.train.steps import (
     make_serve_step,
     make_train_step,
 )
-from repro.utils.hlo import analyze_hlo, xla_cost_analysis
+from repro.utils.hlo import analyze_hlo
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 
@@ -191,7 +191,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, kv_chunk: int = 2048
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()  # PER-DEVICE (SPMD module stats)
-    cost = xla_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo_cost = analyze_hlo(compiled.as_text())  # loop-aware, per-device
 
     n_chips = 1

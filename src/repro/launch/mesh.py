@@ -3,12 +3,10 @@ touches jax device state)."""
 
 from __future__ import annotations
 
-import jax
-
 
 def make_production_mesh(*, multi_pod: bool = False):
-    from repro.distributed.mesh import _axis_type_kwargs
+    from repro.distributed.mesh import make_mesh
 
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)

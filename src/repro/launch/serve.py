@@ -8,6 +8,9 @@ import argparse
 
 
 def main():
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

@@ -211,7 +211,7 @@ def moe_block_ep(
         out = jax.lax.psum(out, model_axis)  # row-parallel combine
         return out.reshape(Bl, Sl, D).astype(xb.dtype), aux
 
-    from repro.distributed.shardmap import shard_map
+    from jax import shard_map
 
     bspec = batch_axes if batch_axes else None
     fn = shard_map(

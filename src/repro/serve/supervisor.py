@@ -7,6 +7,10 @@ recovery is then just the child's own `ServeEngine.recover()` running at
 startup, so a supervised restart and a manual restart are the same code
 path.
 
+This module stays JAX-free: it calls nothing from JAX, so the parent never
+initializes a backend and never holds the chip its children need (a device
+belongs to one process at a time).
+
 Detection:
   death   `Popen.poll()` — any nonzero/signal exit is a crash; exit 0
           means the workload drained and the supervisor is done.

@@ -48,6 +48,9 @@ def build_engine(args: argparse.Namespace) -> ServeEngine:
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--dir", required=True,
                     help="durable store root (WAL + snapshots + heartbeat)")

@@ -287,13 +287,3 @@ def donation_aliases(compiled):
          kind)
         for out, param, pidx, kind in _ALIAS_ENTRY_RE.findall(m.group(1))
     ]
-
-
-def xla_cost_analysis(compiled) -> dict:
-    """Version-stable `compiled.cost_analysis()`: older jax returns a list of
-    per-module dicts (one entry per partition), newer returns the dict
-    directly.  Always hands back a dict (empty when XLA reports nothing)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}

@@ -1,5 +1,8 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — smoke tests and benches see
-1 device; multi-device tests spawn subprocesses with their own flags."""
+1 device; multi-device tests spawn subprocesses with their own flags.  Those
+children get virtual CPU devices (--xla_force_host_platform_device_count);
+that path is for the CPU only and never runs on the chip, where a device
+belongs to one process at a time."""
 
 import os
 import subprocess
@@ -41,12 +44,9 @@ def device_script_runner():
 def _clear_jax_caches_between_modules():
     """Drop compiled executables after each test module.
 
-    jax 0.4.37's CPU backend segfaults inside `backend_compile` once a few
-    hundred distinct programs have been compiled in one process (observed
-    deterministically at ~130 tests into the suite, in a trivial program
-    that compiles fine standalone).  Programs rarely repeat across modules,
-    so releasing the jit caches at module boundaries costs nothing and
-    keeps the accumulated compiler state below the crash threshold."""
+    Programs rarely repeat across modules, so releasing the jit caches at
+    module boundaries costs little and bounds the compiled code each test
+    worker keeps resident while it works through its share of the files."""
     yield
     import jax
 

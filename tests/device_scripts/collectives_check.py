@@ -17,7 +17,7 @@ from repro.distributed.collectives import (
     reduce_scatter_then_allgather,
 )
 from repro.distributed.mesh import make_mesh
-from repro.distributed.shardmap import shard_map
+from jax import shard_map
 
 mesh = make_mesh((2, 4), ("pod", "data"))
 rng = np.random.default_rng(0)

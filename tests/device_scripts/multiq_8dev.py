@@ -18,7 +18,7 @@ from repro.core.pqueue import dist as D
 from repro.core.pqueue import ops as O
 from repro.core.pqueue.state import INF_KEY, make_state
 from repro.distributed.mesh import make_mesh
-from repro.distributed.shardmap import shard_map
+from jax import shard_map
 
 mesh = make_mesh((2, 4), ("pod", "shard"))
 cfg = D.AxisCfg(shard_axes=("shard",), pod_axis="pod")
@@ -34,6 +34,9 @@ st, _ = O.insert(st, keys, vals)
 initial = np.sort(np.asarray(st.keys[st.keys < INF_KEY]).ravel())
 
 
+# jitted: at C <= H the tail arena leaves are (S, 0), and an eager shard_map
+# call cannot reshard zero-size inputs onto the mesh
+@jax.jit
 @partial(
     shard_map,
     mesh=mesh,

@@ -37,3 +37,9 @@ def test_elastic_rescale(device_script_runner):
 def test_dryrun_cell(device_script_runner):
     out = device_script_runner("dryrun_cell_check.py", n_devices=512)
     assert "DRYRUN-CELL-OK" in out
+
+
+@pytest.mark.slow
+def test_chip_smoke_four_chip_phase(device_script_runner):
+    out = device_script_runner("chip_smoke_4dev.py", n_devices=4)
+    assert "CHIP-SMOKE-4DEV-OK" in out

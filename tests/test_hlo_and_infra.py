@@ -7,7 +7,7 @@ import jax.numpy as jnp
 from repro.data.loader import ShardedLoader
 from repro.data.synthetic import SyntheticLMDataset
 from repro.utils.hashing import mix32, shard_of_key
-from repro.utils.hlo import analyze_hlo, xla_cost_analysis
+from repro.utils.hlo import analyze_hlo
 
 
 def test_hlo_walker_counts_loop_trips():
@@ -27,7 +27,7 @@ def test_hlo_walker_counts_loop_trips():
     expected = 2.0 * B * D * D * L
     assert 0.9 * expected <= cost.flops <= 1.2 * expected, (cost.flops, expected)
     # XLA's own count misses the trips:
-    xla_cost = xla_cost_analysis(c)
+    xla_cost = c.cost_analysis()
     assert "flops" in xla_cost, "XLA stopped reporting flops — update walker"
     assert xla_cost["flops"] < expected / 2
 

@@ -1,7 +1,8 @@
 """Repo hygiene: fast checks that keep generated artifacts out of git.
 
-PR 5 committed nothing but `__pycache__/*.pyc` files; this gate makes that
-class of regression impossible to land again."""
+Bytecode (`__pycache__/*.pyc`), hypothesis example databases and JAX
+compilation caches are generated at run time; this gate keeps that class
+of file from being committed."""
 
 import subprocess
 from pathlib import Path
@@ -18,11 +19,12 @@ def _tracked_files():
 
 
 def test_no_bytecode_tracked():
-    """No compiled-python artifacts may be tracked by git."""
+    """No compiled-python, test-cache or compile-cache artifacts may be
+    tracked by git."""
+    generated = {"__pycache__", ".pytest_cache", ".hypothesis", ".jax_cache"}
     bad = [
         f for f in _tracked_files()
-        if f.endswith(".pyc") or "__pycache__" in f.split("/")
-        or ".pytest_cache" in f.split("/")
+        if f.endswith(".pyc") or generated & set(f.split("/"))
     ]
     assert bad == [], f"generated artifacts tracked by git: {bad}"
 
@@ -30,7 +32,8 @@ def test_no_bytecode_tracked():
 def test_gitignore_covers_bytecode():
     """The root .gitignore must keep covering the artifact classes."""
     patterns = (REPO / ".gitignore").read_text().splitlines()
-    for needed in ("__pycache__/", "*.pyc", ".pytest_cache/"):
+    for needed in ("__pycache__/", "*.pyc", ".pytest_cache/", ".hypothesis/",
+                   ".jax_cache/"):
         assert needed in patterns, f".gitignore is missing {needed!r}"
 
 

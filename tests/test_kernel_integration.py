@@ -18,7 +18,7 @@ def test_topk_kernel_path_matches_argsort():
         vals = jnp.asarray(rng.integers(0, 1 << 20, n), jnp.int32)
         k_ref, v_ref = topk_of_merged(keys, vals, m, arm="argsort")
         k_ker, v_ker = topk_of_merged(keys, vals, m,
-                                      arm="interpret@rows_per_block=8")
+                                      arm="interpret")
         np.testing.assert_array_equal(np.asarray(k_ref), np.asarray(k_ker))
         np.testing.assert_array_equal(np.asarray(v_ref), np.asarray(v_ker))
 
@@ -33,7 +33,7 @@ def test_delete_min_identical_through_kernel():
     st, _ = O.insert(st, keys, keys % 97)
 
     res_ref = O.delete_min(st, 8, schedule=Schedule.STRICT_FLAT, active=8)
-    with REG.force_arms({"topk_smallest": "interpret@rows_per_block=8"}):
+    with REG.force_arms({"topk_smallest": "interpret"}):
         res_ker = O.delete_min(st, 8, schedule=Schedule.STRICT_FLAT, active=8)
     np.testing.assert_array_equal(np.asarray(res_ref.keys), np.asarray(res_ker.keys))
     np.testing.assert_array_equal(np.asarray(res_ref.vals), np.asarray(res_ker.vals))

@@ -60,17 +60,61 @@ def test_resolve_precedence_explicit_then_forced_then_default():
         assert REG.resolve("topk_smallest", coords) == "ref"
     with pytest.raises(ValueError, match="not available"):
         REG.resolve("topk_smallest", coords, arm="no_such_arm")
-    # a forced arm that is unavailable on this backend is skipped, not fatal
-    with REG.force_arms({"topk_smallest": "compiled@rows_per_block=8"}):
-        got = REG.resolve("topk_smallest", coords)
-        if not REG.supports_compiled():
-            assert got == spec.default
-    # wildcard force applies to every kernel that has the arm
+    # a forced arm that is unavailable on this backend raises
+    with REG.force_arms({"topk_smallest": "compiled"}):
+        if REG.supports_compiled():
+            assert REG.resolve("topk_smallest", coords) == \
+                "compiled"
+        else:
+            with pytest.raises(ValueError, match="forced arm .* not available"):
+                REG.resolve("topk_smallest", coords)
+    # wildcard force applies to every kernel that declares the arm, and
+    # leaves the others on their default
     with REG.force_arms({"*": "ref"}):
         assert REG.resolve("topk_smallest", coords) == "ref"
         assert REG.resolve("windowed_merge",
                            dict(REG.REGISTRY["windowed_merge"]
                                 .validation_shapes[0])) == "ref"
+        segmin = REG.REGISTRY["segment_min_into"]
+        assert REG.resolve("segment_min_into",
+                           dict(segmin.validation_shapes[0])) == \
+            segmin.default
+
+
+def test_interpret_arms_unavailable_on_tpu():
+    """On the chip only compiled and jnp arms exist: an interpret arm would
+    run the Pallas interpreter on the host and hide the device."""
+    for spec in REG.REGISTRY.values():
+        on_tpu = {a.kind for a in spec.available_arms("tpu")}
+        on_cpu = {a.kind for a in spec.available_arms("cpu")}
+        assert "interpret" not in on_tpu, spec.name
+        assert "jnp" in on_tpu and "compiled" not in on_cpu, spec.name
+        if any(a.kind == "compiled" for a in spec.arms):
+            assert "compiled" in on_tpu and "interpret" in on_cpu, spec.name
+
+
+def test_unavailable_tuned_arm_raises(tmp_path, monkeypatch):
+    """A tuning record naming an arm this backend cannot run is an error,
+    not a silent fall-through to the default."""
+    import jax
+
+    spec = REG.REGISTRY["elim_sort"]
+    coords = dict(spec.tuning_shapes[0])
+    bad = next(a.name for a in spec.arms
+               if not a.available(jax.default_backend()))
+    path = tmp_path / "kernels_bad_arm.json"
+    path.write_text(json.dumps(
+        {"schema": 1, "backend": jax.default_backend(),
+         "jax": jax.__version__,
+         "records": {tuning.TuningCache.key("elim_sort", REG.sig(coords)):
+                     {"arm": bad, "us": 1.0}}}))
+    monkeypatch.setenv(tuning.CACHE_ENV, str(path))
+    tuning.invalidate_cache()
+    try:
+        with pytest.raises(ValueError, match="tuned arm .* not available"):
+            REG.resolve("elim_sort", coords)
+    finally:
+        tuning.invalidate_cache()
 
 
 def test_tuning_cache_round_trip(tmp_path, monkeypatch):

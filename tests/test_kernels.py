@@ -12,6 +12,7 @@ from repro.kernels import ref as REF
 from repro.kernels.ops import merge_sorted_runs, topk_smallest
 
 RNG = np.random.default_rng(0)
+PALLAS_ARMS = ("interpret@rows_per_block=8", "interpret@rows_per_block=32")
 
 
 @pytest.mark.parametrize(
@@ -52,8 +53,9 @@ def test_merge_exact(S, C, R):
     np.testing.assert_array_equal(np.asarray(mv), np.asarray(rv))
 
 
+@pytest.mark.parametrize("arm", PALLAS_ARMS)
 @pytest.mark.parametrize("R,N", [(1, 16), (4, 64), (6, 37), (8, 128), (3, 100)])
-def test_elim_sort_exact(R, N):
+def test_elim_sort_exact(R, N, arm):
     """The elimination-match full sort (bitonic network on (key, tag) pairs)
     must be bit-identical to the stable-argsort reference under heavy
     duplicates and INF-masked lanes — the pre-pass exactness contract."""
@@ -62,8 +64,7 @@ def test_elim_sort_exact(R, N):
     keys = RNG.integers(0, 12, (R, N)).astype(np.int32)  # heavy ties
     keys[RNG.random((R, N)) < 0.3] = INF_KEY  # masked non-insert lanes
     tags = np.tile(np.arange(N, dtype=np.int32), (R, 1))
-    kk, kt = elim_sort(jnp.asarray(keys), jnp.asarray(tags),
-                       arm="interpret@rows_per_block=8")
+    kk, kt = elim_sort(jnp.asarray(keys), jnp.asarray(tags), arm=arm)
     rk, rt = REF.elim_sort_ref(jnp.asarray(keys), jnp.asarray(tags))
     np.testing.assert_array_equal(np.asarray(kk), np.asarray(rk))
     np.testing.assert_array_equal(np.asarray(kt), np.asarray(rt))
@@ -109,11 +110,12 @@ def test_merge_against_local_semantics():
     np.testing.assert_array_equal(np.asarray(nk), np.asarray(mk))
 
 
+@pytest.mark.parametrize("arm", PALLAS_ARMS)
 @pytest.mark.parametrize(
     "S,H,R", [(4, 64, 16), (8, 128, 128), (2, 256, 7), (1, 64, 1),
               (6, 100, 60), (3, 8, 8)]
 )
-def test_windowed_merge_exact(S, H, R):
+def test_windowed_merge_exact(S, H, R, arm):
     """The windowed-merge kernel (full H+R window, nothing dropped) must be
     bit-identical to BOTH the lexicographic reference and the
     positional-stable rank merge in local.merge_head_run."""
@@ -137,7 +139,7 @@ def test_windowed_merge_exact(S, H, R):
         run_q[s, :n] = 1000 + np.arange(n)
     args = tuple(jnp.asarray(a)
                  for a in (head_k, head_v, head_q, run_k, run_v, run_q))
-    ker = windowed_merge(*args, arm="interpret@rows_per_block=4")
+    ker = windowed_merge(*args, arm=arm)
     ref = windowed_merge(*args, arm="ref")
     jnp_path = merge_head_run(*args, arm="rank")
     for a, b, c in zip(ker, ref, jnp_path):
@@ -155,7 +157,7 @@ def test_tiered_insert_kernel_path_matches():
     keys = jnp.asarray(rng.integers(0, 300, 96), jnp.int32)
     vals = jnp.asarray(rng.integers(0, 99, 96), jnp.int32)
     st_ref, _ = O.insert(make_state(4, 64, head_width=16), keys, vals)
-    with REG.force_arms({"windowed_merge": "interpret@rows_per_block=4"}):
+    with REG.force_arms({"windowed_merge": "interpret@rows_per_block=8"}):
         st_ker, _ = O.insert(make_state(4, 64, head_width=16), keys, vals)
     for a, b in zip(
         __import__("jax").tree.leaves(st_ref), __import__("jax").tree.leaves(st_ker)

@@ -25,13 +25,13 @@ import pytest
 from repro.core.pqueue import ops as O
 from repro.core.pqueue.schedules import Schedule
 from repro.core.pqueue.state import make_state
-from repro.utils.hlo import donation_aliases, xla_cost_analysis
+from repro.utils.hlo import donation_aliases
 
 ROOT = Path(__file__).resolve().parents[1]
 
 
 # ---------------------------------------------------------------------------
-# 1. capacity-sublinear hot path (xla_cost_analysis regression)
+# 1. capacity-sublinear hot path (cost_analysis regression)
 # ---------------------------------------------------------------------------
 
 
@@ -51,7 +51,7 @@ def _hot_path_cost(schedule, capacity, S=16, B=64):
     args = (st, jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
             jnp.zeros(B, jnp.int32), jax.random.key(0))
     compiled = step.lower(*args).compile()
-    cost = xla_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     return float(cost.get("flops", 0.0)), float(cost.get("bytes accessed", 0.0))
 
 
