@@ -1,0 +1,8 @@
+"""Fused window: mode transitions (`SmartPQStats.transitions`) per 1000
+steps of the timed window."""
+
+
+def read(view):
+    if view.steps <= 0:
+        return None
+    return 1e3 * view.counters["transitions"] / view.steps
