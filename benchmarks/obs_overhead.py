@@ -4,10 +4,14 @@ Two identical scheduler sessions drive the same delete-dominated fused
 windows (the fig9 ins0 slice of the serving path: budget-B deleteMin per
 tick, zero arrivals) — one with the disabled Observability bundle (every
 metrics/tracer write early-outs on a single branch), one with metrics AND
-tracing fully on.  Timed windows are interleaved off/on so clock drift and
-allocator warmup hit both sides equally; refill windows (pure insert,
-untimed) between them keep the queue deep so the timed path stays
-deleteMin-dominated throughout.
+tracing fully on.  Timed windows are interleaved in ABBA blocks (off, on,
+then on, off): the window that runs first after a refill is the slower
+one, and a block's ratio (its two on-windows over its two off-windows)
+cancels that order and any drift across the block; the reported ratio is
+the median over blocks, so a burst of load from other processes skews one
+block, not the result.  Refill windows (pure insert, untimed) after each
+timed pair keep the queue deep so the timed path stays deleteMin-dominated
+throughout.
 
 Two acceptance properties ride on these records (recorded here, asserted
 in tests/test_obs.py):
@@ -96,8 +100,9 @@ def measure(
     iters: int = 12, K: int = 16, batch_size: int = 64, seed: int = 11
 ):
     """Interleaved obs-off/obs-on timing of the delete-dominated window
-    path; returns median per-window/per-op times, their ratio, and the
-    two sessions' dispatched uid streams (for the bit-identity check)."""
+    path in `iters` ABBA blocks; returns median per-window/per-op times,
+    the median over blocks of the on/off ratio, and the two sessions'
+    dispatched uid streams (for the bit-identity check)."""
     sessions = [
         ("off", _new_session(
             Observability(metrics=False, tracing=False), batch_size, seed
@@ -111,11 +116,15 @@ def measure(
         _refill(sess, K, batch_size)  # window drains K*B, refill restores
         _dispatch_window(sess, K, batch_size, timed=False)  # compile+warm
         _refill(sess, K, batch_size)
+    blocks = []
     for _ in range(iters):
-        for _, sess in sessions:  # interleaved: drift hits both equally
-            _dispatch_window(sess, K, batch_size, timed=True)
-        for _, sess in sessions:
-            _refill(sess, K, batch_size)
+        for order in (sessions, sessions[::-1]):  # ABBA: off, on, on, off
+            for _, sess in order:
+                _dispatch_window(sess, K, batch_size, timed=True)
+            for _, sess in sessions:
+                _refill(sess, K, batch_size)
+        off, on = (sum(sess["times"][-2:]) for _, sess in sessions)
+        blocks.append(on / off)
     ops = K * batch_size
     out = {"ops_per_window": ops}
     for tag, sess in sessions:
@@ -123,7 +132,7 @@ def measure(
         out[f"us_window_{tag}"] = med
         out[f"us_per_op_{tag}"] = med / ops
         out[f"uids_{tag}"] = sess["uids"]
-    out["ratio"] = out["us_per_op_on"] / out["us_per_op_off"]
+    out["ratio"] = float(np.median(blocks))
     out["identical"] = out["uids_on"] == out["uids_off"]
     # The instrumented session, for callers that inspect its registry/trace.
     out["sched_on"] = sessions[1][1]["sched"]

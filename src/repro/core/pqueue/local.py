@@ -286,10 +286,12 @@ def compact_tail(state: PQState) -> PQState:
     find appends since the last compaction)."""
     if state.tail_width == 0:
         return state
-    tk, tq, tv = _key_seq_sort(*_tail_window_kqv(state))
-    hq, tq, nseq = _renumber_seqs(
-        state.head_seq, tq, state.head_size, state.tail_size
-    )
+    # named for the device trace, whichever of insert or refill fired it
+    with jax.named_scope("pq.compact"):
+        tk, tq, tv = _key_seq_sort(*_tail_window_kqv(state))
+        hq, tq, nseq = _renumber_seqs(
+            state.head_seq, tq, state.head_size, state.tail_size
+        )
     return dataclasses.replace(
         state, tail_keys=tk, tail_vals=tv, tail_seq=tq, head_seq=hq,
         tail_start=jnp.zeros_like(state.tail_start),

@@ -248,69 +248,76 @@ class SmartPQ:
             num_clients = c.num_shards
         num_clients = jnp.asarray(num_clients, jnp.int32)
 
-        ins_mask = ops == OP_INSERT
-        n_rejected = stats.rejected
-        if jnp.issubdtype(jnp.asarray(keys).dtype, jnp.floating):
-            # Admission-boundary sanitization: float key batches may carry
-            # NaN/±inf — reject (-> INF sentinel, counted) instead of
-            # letting IEEE sort semantics order them into the queue.  The
-            # dtype test is trace-time: integer batches compile the exact
-            # pre-sanitizer graph.
-            keys, bad_keys = O.sanitize_keys(keys)
-            n_rejected = n_rejected + jnp.sum(
-                bad_keys & ins_mask
-            ).astype(jnp.int32)
-            ins_mask = ins_mask & ~bad_keys
-        b_ins = jnp.sum(ins_mask).astype(jnp.int32)
-        b_del = jnp.sum(ops == OP_DELETE_MIN).astype(jnp.int32)
+        # Each phase of the step runs under a `jax.named_scope` named in
+        # `repro.obs.profiling.LAYER_SCOPES`.  A scope is metadata only (the
+        # HLO `op_name`): the compiled program is the same without it, and a
+        # device trace can attribute each op's time to its phase.
+        with jax.named_scope("pq.decide"):
+            ins_mask = ops == OP_INSERT
+            n_rejected = stats.rejected
+            if jnp.issubdtype(jnp.asarray(keys).dtype, jnp.floating):
+                # Admission-boundary sanitization: float key batches may carry
+                # NaN/±inf — reject (-> INF sentinel, counted) instead of
+                # letting IEEE sort semantics order them into the queue.  The
+                # dtype test is trace-time: integer batches compile the exact
+                # pre-sanitizer graph.
+                keys, bad_keys = O.sanitize_keys(keys)
+                n_rejected = n_rejected + jnp.sum(
+                    bad_keys & ins_mask
+                ).astype(jnp.int32)
+                ins_mask = ins_mask & ~bad_keys
+            b_ins = jnp.sum(ins_mask).astype(jnp.int32)
+            b_del = jnp.sum(ops == OP_DELETE_MIN).astype(jnp.int32)
 
-        batch_min = jnp.min(jnp.where(ins_mask, keys, INF_KEY))
-        batch_max = jnp.max(jnp.where(ins_mask, keys, 0))
-        n_insert = stats.n_insert + b_ins
-        n_delete = stats.n_delete + b_del
-        min_key = jnp.minimum(stats.min_key, batch_min)
-        max_key = jnp.maximum(stats.max_key, batch_max)
+            batch_min = jnp.min(jnp.where(ins_mask, keys, INF_KEY))
+            batch_max = jnp.max(jnp.where(ins_mask, keys, 0))
+            n_insert = stats.n_insert + b_ins
+            n_delete = stats.n_delete + b_del
+            min_key = jnp.minimum(stats.min_key, batch_min)
+            max_key = jnp.maximum(stats.max_key, batch_max)
 
-        # -- decision (paper Fig. 8 decisionTree(), on-device) ---------------
-        do_decide = (stats.step % c.decision_interval) == 0
-        total_ops = jnp.maximum(n_insert + n_delete, 1)
-        key_range = jnp.where(
-            min_key <= max_key, jnp.maximum(max_key - min_key, 1), 1
-        )
-        feats = featurize_jnp(
-            num_clients,
-            state.total_size,
-            key_range,
-            n_insert.astype(jnp.float32) / total_ops.astype(jnp.float32),
-        )
-        pred = tree_predict(self.packed, feats)
-        # NEUTRAL (and any future >= NUM_MODES sentinel) keeps the mode; a
-        # NEGATIVE class (possible only from a corrupted packed tree) must
-        # not reach the switch either.
-        keep = (~do_decide) | (pred >= NUM_MODES) | (pred < 0)
-        new_mode = jnp.where(keep, stats.mode, pred).astype(jnp.int32)
-        if mode_override is not None:
-            ov = jnp.asarray(mode_override, jnp.int32)
-            new_mode = jnp.where(ov >= 0, ov, new_mode)
-        # Hard clamp before `lax.switch`: an out-of-range branch index —
-        # whether from a corrupt tree label, a corrupt carry, or a bad
-        # override — degrades to the nearest valid mode instead of UB.
-        new_mode = jnp.clip(new_mode, 0, NUM_MODES - 1)
-        transitions = stats.transitions + (new_mode != stats.mode).astype(jnp.int32)
-        # Reset windowed op counters after each decision.
-        n_insert = jnp.where(do_decide, 0, n_insert)
-        n_delete = jnp.where(do_decide, 0, n_delete)
+            # -- decision (paper Fig. 8 decisionTree(), on-device) -----------
+            do_decide = (stats.step % c.decision_interval) == 0
+            total_ops = jnp.maximum(n_insert + n_delete, 1)
+            key_range = jnp.where(
+                min_key <= max_key, jnp.maximum(max_key - min_key, 1), 1
+            )
+            feats = featurize_jnp(
+                num_clients,
+                state.total_size,
+                key_range,
+                n_insert.astype(jnp.float32) / total_ops.astype(jnp.float32),
+            )
+            pred = tree_predict(self.packed, feats)
+            # NEUTRAL (and any future >= NUM_MODES sentinel) keeps the mode; a
+            # NEGATIVE class (possible only from a corrupted packed tree) must
+            # not reach the switch either.
+            keep = (~do_decide) | (pred >= NUM_MODES) | (pred < 0)
+            new_mode = jnp.where(keep, stats.mode, pred).astype(jnp.int32)
+            if mode_override is not None:
+                ov = jnp.asarray(mode_override, jnp.int32)
+                new_mode = jnp.where(ov >= 0, ov, new_mode)
+            # Hard clamp before `lax.switch`: an out-of-range branch index —
+            # whether from a corrupt tree label, a corrupt carry, or a bad
+            # override — degrades to the nearest valid mode instead of UB.
+            new_mode = jnp.clip(new_mode, 0, NUM_MODES - 1)
+            transitions = stats.transitions + (new_mode != stats.mode).astype(jnp.int32)
+            # Reset windowed op counters after each decision.
+            n_insert = jnp.where(do_decide, 0, n_insert)
+            n_delete = jnp.where(do_decide, 0, n_delete)
 
         # -- elimination/combining pre-pass ----------------------------------
         if c.eliminate:
-            if presorted is None:
-                presorted = L.sort_op_log(jnp.where(ins_mask, keys, INF_KEY))
-            sk, stg = presorted
-            elim_k, elim_v, n_elim, keep_lane = O.elim_split(
-                state, sk, stg, vals, b_del
-            )
-            ins_mask = ins_mask & keep_lane
-            active = b_del - n_elim
+            with jax.named_scope("pq.eliminate"):
+                if presorted is None:
+                    presorted = L.sort_op_log(
+                        jnp.where(ins_mask, keys, INF_KEY))
+                sk, stg = presorted
+                elim_k, elim_v, n_elim, keep_lane = O.elim_split(
+                    state, sk, stg, vals, b_del
+                )
+                ins_mask = ins_mask & keep_lane
+                active = b_del - n_elim
         else:
             n_elim = jnp.int32(0)
             active = b_del
@@ -321,14 +328,16 @@ class SmartPQ:
         # the HotTier — the cold tail never crosses the switch boundary, so
         # the conditional's operand/result copies are head-sized, not
         # capacity-sized (the big CPU win of the fused window).
-        state, dropped = insert(state, keys, vals, mask=ins_mask)
-        # Count the refill BEFORE ensure_head consumes the predicate — the
-        # same expression gates the lax.cond inside, so the counter tracks
-        # actual guarded-refill firings, not an approximation.
-        head_refills = stats.head_refills + SCH.head_refill_pred(
-            state, B
-        ).astype(jnp.int32)
-        state = SCH.ensure_head(state, B)
+        with jax.named_scope("pq.insert"):
+            state, dropped = insert(state, keys, vals, mask=ins_mask)
+        with jax.named_scope("pq.refill"):
+            # Count the refill BEFORE ensure_head consumes the predicate —
+            # the same expression gates the lax.cond inside, so the counter
+            # tracks actual guarded-refill firings, not an approximation.
+            head_refills = stats.head_refills + SCH.head_refill_pred(
+                state, B
+            ).astype(jnp.int32)
+            state = SCH.ensure_head(state, B)
         total = state.total_size
 
         def run(schedule: Schedule):
@@ -336,18 +345,21 @@ class SmartPQ:
 
             def branch(operand):
                 hot_in, rng_ = operand
-                return fn(hot_in, total, B, active, rng_, c.npods)
+                with jax.named_scope(f"pq.schedule.{schedule.name.lower()}"):
+                    return fn(hot_in, total, B, active, rng_, c.npods)
 
             return branch
 
-        hot, out_k, out_v, n_out = jax.lax.switch(
-            new_mode,
-            [run(s) for s in c.mode_schedules],
-            (SCH.hot_tier(state), rng),
-        )
+        with jax.named_scope("pq.schedule"):
+            hot, out_k, out_v, n_out = jax.lax.switch(
+                new_mode,
+                [run(s) for s in c.mode_schedules],
+                (SCH.hot_tier(state), rng),
+            )
         res = DeleteResult(SCH.attach_hot(state, hot), out_k, out_v, n_out)
         if c.eliminate:
-            res = O.merge_eliminated(elim_k, elim_v, n_elim, res)
+            with jax.named_scope("pq.eliminate"):
+                res = O.merge_eliminated(elim_k, elim_v, n_elim, res)
 
         new_stats = SmartPQStats(
             step=stats.step + 1,
@@ -408,21 +420,22 @@ class SmartPQ:
             jnp.asarray(num_clients, jnp.int32), (K,)
         )
 
-        if jnp.issubdtype(jnp.asarray(keys).dtype, jnp.floating):
-            keys, bad = O.sanitize_keys(keys)
-            n_rej = jnp.sum(bad & (ops == OP_INSERT)).astype(jnp.int32)
-            carry = carry._replace(
-                stats=carry.stats._replace(
-                    rejected=carry.stats.rejected + n_rej
+        with jax.named_scope("pq.presort"):
+            if jnp.issubdtype(jnp.asarray(keys).dtype, jnp.floating):
+                keys, bad = O.sanitize_keys(keys)
+                n_rej = jnp.sum(bad & (ops == OP_INSERT)).astype(jnp.int32)
+                carry = carry._replace(
+                    stats=carry.stats._replace(
+                        rejected=carry.stats.rejected + n_rej
+                    )
                 )
-            )
 
-        if c.eliminate:
-            ins = ops == OP_INSERT
-            sk, stg = L.sort_op_log(jnp.where(ins, keys, INF_KEY))
-        else:  # placeholder lanes keep the scan xs structure static
-            sk = jnp.zeros((K, B), jnp.int32)
-            stg = jnp.zeros((K, B), jnp.int32)
+            if c.eliminate:
+                ins = ops == OP_INSERT
+                sk, stg = L.sort_op_log(jnp.where(ins, keys, INF_KEY))
+            else:  # placeholder lanes keep the scan xs structure static
+                sk = jnp.zeros((K, B), jnp.int32)
+                stg = jnp.zeros((K, B), jnp.int32)
 
         if mode_override is None:
 

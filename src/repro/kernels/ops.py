@@ -4,7 +4,9 @@ Every wrapper resolves its implementation arm through
 `repro.kernels.registry.resolve` (explicit ``arm=`` > force override >
 tuning-cache winner > safe jnp default; see that module's docstring) and
 then runs a jitted implementation keyed on the resolved arm, so forcing or
-re-tuning an arm never collides with a stale jit cache.  Padding to the
+re-tuning an arm never collides with a stale jit cache.  The dispatch runs
+under a `kernel.<kernel>.<arm>` named scope, which names the arm in each
+op's HLO metadata and so in a device trace.  Padding to the
 networks' lane-dense power-of-two widths happens in the Pallas wrappers;
 platform policy (which arms exist where) lives entirely in the registry —
 there is deliberately not a single backend check in this file.
@@ -36,6 +38,14 @@ from repro.kernels.twochoice import multiq_select_pallas, twochoice_pick_pallas
 from repro.kernels.windowed_merge import windowed_merge_pallas
 
 
+def _scope(kernel: str, arm: str):
+    """`kernel.<kernel>.<arm>` named scope around a dispatch, so that a
+    device trace can tell which kernel arm an op belongs to.  The arm's
+    tuning suffix (`@rows_per_block=8`) is dropped: `@` and `=` have no
+    place in an HLO `op_name` path."""
+    return jax.named_scope(f"kernel.{kernel}.{arm.split('@')[0]}")
+
+
 # ---------------------------------------------------------------------------
 # bitonic top-k — the deleteMin tournament
 # ---------------------------------------------------------------------------
@@ -51,8 +61,9 @@ def topk_smallest(
     lane-dense power of two with sentinels (kernels.bitonic_topk)."""
     coords = {"R": keys.shape[0], "N": keys.shape[1], "k": k,
               "dtype": str(keys.dtype)}
-    return _topk_dispatch(keys, vals, k, REG.resolve("topk_smallest",
-                                                     coords, arm))
+    arm = REG.resolve("topk_smallest", coords, arm)
+    with _scope("topk_smallest", arm):
+        return _topk_dispatch(keys, vals, k, arm)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "arm"))
@@ -80,7 +91,9 @@ def elim_sort(
     """Row-wise full ascending sort of (key, tag) pairs — the elimination
     match pre-pass."""
     coords = {"R": keys.shape[0], "B": keys.shape[1]}
-    return _elim_dispatch(keys, tags, REG.resolve("elim_sort", coords, arm))
+    arm = REG.resolve("elim_sort", coords, arm)
+    with _scope("elim_sort", arm):
+        return _elim_dispatch(keys, tags, arm)
 
 
 @functools.partial(jax.jit, static_argnames=("arm",))
@@ -108,10 +121,10 @@ def twochoice_counts(
 ) -> jnp.ndarray:
     """Per-shard commit counts of the MULTIQ two-choice probe.  (S,) int32."""
     coords = {"S": mins.shape[0], "m": choice_a.shape[0]}
-    return _twochoice_dispatch(
-        mins, choice_a, choice_b, act.astype(jnp.int32),
-        REG.resolve("twochoice_counts", coords, arm),
-    )
+    arm = REG.resolve("twochoice_counts", coords, arm)
+    with _scope("twochoice_counts", arm):
+        return _twochoice_dispatch(mins, choice_a, choice_b,
+                                   act.astype(jnp.int32), arm)
 
 
 @functools.partial(jax.jit, static_argnames=("arm",))
@@ -134,8 +147,9 @@ def multiq_select_topm(
     position-tag) pairs, payloads gathered by tag afterwards — bit-identical
     to the stable-argsort reference."""
     coords = {"S": win_k.shape[0], "m": win_k.shape[1]}
-    return _multiq_dispatch(win_k, win_v, take,
-                            REG.resolve("multiq_select_topm", coords, arm))
+    arm = REG.resolve("multiq_select_topm", coords, arm)
+    with _scope("multiq_select_topm", arm):
+        return _multiq_dispatch(win_k, win_v, take, arm)
 
 
 @functools.partial(jax.jit, static_argnames=("arm",))
@@ -181,12 +195,14 @@ def windowed_merge(
     coords = {"S": head_k.shape[0], "H": head_k.shape[1],
               "R": run_k.shape[1]}
     arm = REG.resolve("windowed_merge", coords, arm)
-    if arm == "rank":
-        from repro.core.pqueue.local import rank_merge_head_run
+    with _scope("windowed_merge", arm):
+        if arm == "rank":
+            from repro.core.pqueue.local import rank_merge_head_run
 
-        return rank_merge_head_run(head_k, head_v, head_q,
-                                   run_k, run_v, run_q)
-    return _wmerge_dispatch(head_k, head_v, head_q, run_k, run_v, run_q, arm)
+            return rank_merge_head_run(head_k, head_v, head_q,
+                                       run_k, run_v, run_q)
+        return _wmerge_dispatch(head_k, head_v, head_q, run_k, run_v, run_q,
+                                arm)
 
 
 @functools.partial(jax.jit, static_argnames=("arm",))
@@ -229,8 +245,9 @@ def merge_sorted_runs(
     """Smallest C of (buffer ∪ run), ascending per row."""
     coords = {"S": buf_k.shape[0], "C": buf_k.shape[1],
               "R": run_k.shape[1]}
-    return _msr_dispatch(buf_k, buf_v, run_k, run_v,
-                         REG.resolve("merge_sorted_runs", coords, arm))
+    arm = REG.resolve("merge_sorted_runs", coords, arm)
+    with _scope("merge_sorted_runs", arm):
+        return _msr_dispatch(buf_k, buf_v, run_k, run_v, arm)
 
 
 @functools.partial(jax.jit, static_argnames=("arm",))
@@ -264,8 +281,9 @@ def segment_min_into(
     Arms (`kernels.segmin`): direct scatter vs sort-dedup-scatter — an
     associative/commutative int32 min, so bit-identical either way."""
     coords = {"E": tgt.shape[0], "n": dist.shape[0]}
-    return _segmin_dispatch(dist, tgt, vals,
-                            REG.resolve("segment_min_into", coords, arm))
+    arm = REG.resolve("segment_min_into", coords, arm)
+    with _scope("segment_min_into", arm):
+        return _segmin_dispatch(dist, tgt, vals, arm)
 
 
 @functools.partial(jax.jit, static_argnames=("arm",))

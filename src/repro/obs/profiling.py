@@ -10,12 +10,31 @@ annotation cost unless a dump directory armed the session:
   trace_session(dir)    jax.profiler.trace context writing an xplane dump
                         under `dir`; `None` -> no-op nullcontext, so call
                         sites wrap unconditionally.
+
+`LAYER_SCOPES` lists the `jax.named_scope`s that divide the fused window
+(`SmartPQ.run_window` / `step`) into its layers.  A scope is metadata only:
+it lands in each HLO instruction's `op_name` (`.../pq.schedule/cond/
+branch_0_fun/pq.schedule.hier/...`) and changes no program.  An op belongs
+to the innermost `pq.*` scope of its `op_name`; a schedule branch's scope
+(`pq.schedule.<schedule name, lower case>`) rolls up into `pq.schedule`.
+Each kernel dispatch of `repro.kernels.ops` adds a `kernel.<kernel>.<arm>`
+scope, orthogonal to the layers.
 """
 
 from __future__ import annotations
 
 import contextlib
 from typing import ContextManager, Optional
+
+LAYER_SCOPES = (
+    "pq.presort",  # run_window: float sanitising, the op-log sort
+    "pq.decide",  # step: batch stats, features, tree, mode select
+    "pq.eliminate",  # step: elimination split and merge
+    "pq.insert",  # step: routing and the tiered insert
+    "pq.refill",  # step: the guarded head refill
+    "pq.schedule",  # step: the mode switch and its branches
+    "pq.compact",  # local.compact_tail, from insert or refill
+)
 
 
 def annotate(name: str) -> ContextManager[None]:
@@ -40,4 +59,4 @@ def trace_session(dump_dir: Optional[str]) -> ContextManager[None]:
     return trace(str(dump_dir))
 
 
-__all__ = ["annotate", "trace_session"]
+__all__ = ["LAYER_SCOPES", "annotate", "trace_session"]

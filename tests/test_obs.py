@@ -288,15 +288,17 @@ def test_obs_on_off_dispatch_streams_bit_identical():
 @pytest.mark.slow
 def test_obs_overhead_within_budget():
     """The obs_overhead bench's acceptance bar: telemetry fully on costs
-    <= 1.05x per-op on the delete-dominated window path (interleaved
-    timing; both sessions run the same compiled program)."""
+    <= 1.05x per-op on the delete-dominated window path (both sessions run
+    the same compiled program).  Timed in 32 interleaved ABBA blocks and
+    read as the median block ratio, so that the order of the two sessions
+    and bursts of load from other processes on the same cores cancel."""
     repo = Path(__file__).resolve().parents[1]
     sys.path.insert(0, str(repo))
     try:
         from benchmarks.obs_overhead import measure
     finally:
         sys.path.pop(0)
-    r = measure(iters=10)
+    r = measure(iters=32)
     assert r["identical"]
     assert r["ratio"] <= 1.05, (
         f"telemetry overhead {r['ratio']:.3f}x exceeds the 1.05x budget "
