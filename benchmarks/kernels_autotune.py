@@ -64,8 +64,9 @@ def run(quick: bool = False):
         if interp:
             fields["vs_interpret"] = round(min(interp) / disp_us, 3)
             derived += f";vs_interpret={fields['vs_interpret']:.2f}x"
-        if spec.default in timings:
-            fields["vs_default"] = round(timings[spec.default] / disp_us, 3)
+        default = spec.default_for()
+        if default in timings:
+            fields["vs_default"] = round(timings[default] / disp_us, 3)
             derived += f";vs_default={fields['vs_default']:.2f}x"
         emit(f"kernels_autotune/{spec.name}/{sig}", disp_us, derived,
              **fields)

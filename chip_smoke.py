@@ -268,20 +268,21 @@ def kernels_phase(*, resolved=(), seed: int = 0) -> list:
     checked = []
     for spec in REG.REGISTRY.values():
         fn = getattr(K, spec.name)
+        default = spec.default_for()
         arms = [a.name for a in spec.available_arms() if a.kind == "compiled"]
         for coords in spec.tuning_shapes:
             args, kw = spec.make_inputs(coords, np.random.default_rng(seed))
-            base = jax.tree.leaves(fn(*args, arm=spec.default, **kw))
+            base = jax.tree.leaves(fn(*args, arm=default, **kw))
             for arm in arms:
                 got = jax.tree.leaves(fn(*args, arm=arm, **kw))
                 same = all(np.array_equal(np.asarray(a), np.asarray(b))
                            for a, b in zip(base, got))
                 log("kernels", kernel=spec.name, arm=arm,
                     shape=REG.sig(coords).replace(",", ";"),
-                    equal_to=spec.default if same else "NOT-EQUAL")
+                    equal_to=default if same else "NOT-EQUAL")
                 if not same:
                     raise AssertionError(f"{spec.name}: {arm} != "
-                                         f"{spec.default} at {coords}")
+                                         f"{default} at {coords}")
                 checked.append((spec.name, arm, REG.sig(coords)))
     return checked
 

@@ -69,9 +69,11 @@ def merge_head_run(
     Positional-stable (ties order head before run, in-position within each),
     which — together with the strict head/tail boundary split — keeps head
     equal-key entries in seq order without ever comparing seqs on the hot
-    path.  Dispatches through the `windowed_merge` registry entry: the
-    ``rank`` arm is `rank_merge_head_run` below (the XLA:CPU production
-    path); the Pallas arms run the bitonic windowed-merge network
+    path.  Dispatches through the `windowed_merge` registry entry: on the
+    TPU the ``sort`` arm, one stable key sort of the concatenated row that
+    carries val and seq along with no gather (`kernels.ops`); on
+    every other backend the ``rank`` arm, `rank_merge_head_run` below; the
+    Pallas arms run the bitonic windowed-merge network
     (`kernels.windowed_merge`).  All arms are bit-identical (tested).
 
     Cost is O(H + R) per shard row — independent of the queue capacity.
@@ -91,13 +93,14 @@ def rank_merge_head_run(
     run_q: jnp.ndarray,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The ``rank`` arm of `merge_head_run` — scatter- and sort-free
-    searchsorted rank merge (registered in `repro.kernels.registry`)."""
+    searchsorted rank merge (registered in `repro.kernels.registry`), the
+    default off the TPU; on the TPU its gathers make it the slowest arm."""
     S, H = head_k.shape
     R = run_k.shape[1]
     # Gather formulation (XLA:CPU scatter is a serialized per-index loop —
-    # the old position-scatter was the single hottest op of the step; wide
-    # variadic sorts degrade superlinearly, so a concat-and-stable-sort is
-    # no better).  Each head element's output position is its own index
+    # the old position-scatter was the single hottest op of the step; XLA:CPU
+    # wide variadic sorts degrade superlinearly, so a concat-and-sort is no
+    # better there).  Each head element's output position is its own index
     # plus its rank among the run ('left': count strictly less — the stable
     # head-before-run tie break); pos_head is strictly increasing, so for
     # every output slot p a searchsorted finds whether p is a head slot

@@ -15,21 +15,27 @@ cost model (bytes / compare-ops) for the roofline records.
     > force override                        (`force_arms` / REPRO_PQ_KERNEL_ARM)
     > tuning-cache winner                   (`kernels.tuning`, keyed by
                                              backend + jax version + shape)
-    > the spec's safe default               (a jnp arm — the rule whenever
-                                             no tuning record exists)
+    > the spec's default for this backend   (a jnp arm — the rule whenever
+                                             no tuning record exists:
+                                             `KernelSpec.default_for`)
 
 An explicit, forced or tuned arm that is not available on this backend
 raises: nothing falls through to another arm in silence.
 
-Platform awareness lives in `Arm.available`: compiled (non-interpret)
-Pallas arms exist only on TPU (`supports_compiled`), and interpret arms
-exist everywhere BUT the TPU — on the chip the Python-interpreted kernel
-bodies would hide the device.  GPU deliberately gets the jnp arms: the
-Mosaic kernels do not lower to Triton.
+Platform awareness lives in `Arm.available` and `KernelSpec.default_for`.
+A default differs by backend where the platforms disagree on what is cheap:
+`windowed_merge` takes the gather-free ``sort`` arm on the TPU (a gather
+there costs as much as a sort of the whole row) and the ``rank`` arm
+everywhere else.  Compiled (non-interpret) Pallas arms exist only on TPU
+(`supports_compiled`), and interpret arms exist everywhere BUT the TPU —
+on the chip the Python-interpreted kernel bodies would hide the device.
+GPU deliberately gets the jnp arms: the Mosaic kernels do not lower to
+Triton.
 
-Arm naming: ``ref`` / ``argsort`` / ``rank`` / ``scatter`` / ``sorted`` are
-jnp arms; Pallas arms are ``interpret`` / ``compiled`` with tuning-axis
-values appended as ``@axis=value`` (e.g. ``interpret@rows_per_block=8``).
+Arm naming: ``ref`` / ``argsort`` / ``rank`` / ``sort`` / ``scatter`` /
+``sorted`` are jnp arms; Pallas arms are ``interpret`` / ``compiled`` with
+tuning-axis values appended as ``@axis=value`` (e.g.
+``interpret@rows_per_block=8``).
 All arms of a kernel are bit-identical on its contract inputs (parity-swept
 by tests/test_kernel_registry.py); tuning only ever changes speed.
 """
@@ -126,6 +132,8 @@ class KernelSpec:
     default: the safe arm used when nothing forces or tunes the choice —
              always a jnp arm, so a missing/corrupt tuning cache can never
              pick a slower-or-unavailable path.
+    backend_defaults: (backend, arm) pairs that replace `default` on
+             that backend (also jnp arms); see `default_for`.
     validation_shapes: coordinate dicts the parity tests sweep (small).
     tuning_shapes:     coordinate dicts the autotune harness benchmarks and
                        the chip smoke run checks: the shapes the queue's
@@ -142,6 +150,12 @@ class KernelSpec:
     tuning_shapes: Tuple[Mapping[str, object], ...]
     make_inputs: Callable
     cost_model: Callable
+    backend_defaults: Tuple[Tuple[str, str], ...] = ()
+
+    def default_for(self, backend: Optional[str] = None) -> str:
+        """The default arm on `backend` (the current one when None)."""
+        backend = backend or jax.default_backend()
+        return dict(self.backend_defaults).get(backend, self.default)
 
     def arm(self, name: str) -> Arm:
         for a in self.arms:
@@ -284,7 +298,8 @@ def resolve(name: str, coords: Mapping[str, object],
     if winner is not None:
         return chosen(winner, "tuned")
 
-    return _note_resolution(name, coords, spec.default, "default")
+    return _note_resolution(name, coords, spec.default_for(backend),
+                            "default")
 
 
 def arm_kwargs(name: str, arm: str) -> Dict[str, int]:
@@ -466,7 +481,7 @@ def _cost_segmin(c):
 
 
 def _spec(name, jnp_arms, default, axes, validation, tuning_shapes,
-          make_inputs, cost_model) -> KernelSpec:
+          make_inputs, cost_model, backend_defaults=()) -> KernelSpec:
     # axes=None: jnp-only kernel (no Pallas path); axes={}: Pallas arms
     # with no tuning axes beyond interpret/compiled.
     pallas = _pallas_arms(axes) if axes is not None else ()
@@ -476,6 +491,7 @@ def _spec(name, jnp_arms, default, axes, validation, tuning_shapes,
         validation_shapes=tuple(validation),
         tuning_shapes=tuple(tuning_shapes),
         make_inputs=make_inputs, cost_model=cost_model,
+        backend_defaults=tuple(backend_defaults),
     )
 
 
@@ -536,7 +552,16 @@ REGISTRY: Dict[str, KernelSpec] = {
         ),
         _spec(
             "windowed_merge",
-            jnp_arms=("ref", "rank"), default="rank",
+            # sort: one stable (key, val, seq) sort of the concatenated
+            # row, no gather.  It is the default on the TPU, where the rank
+            # arm's searchsorted loops and picks are gathers that cost as
+            # much each as the whole sort.  Everywhere else rank stays:
+            # XLA:CPU gathers are cheap and its wide variadic sort is 4-6x
+            # slower at the tuning shapes (the CPU timing gate in
+            # tests/test_tiered_perf.py compares against BENCH_pq.json,
+            # measured with rank).
+            jnp_arms=("ref", "rank", "sort"), default="rank",
+            backend_defaults=(("tpu", "sort"),),
             axes={"rows_per_block": (8, 32)},
             validation=(
                 {"S": 4, "H": 64, "R": 16}, {"S": 2, "H": 256, "R": 7},
@@ -544,8 +569,9 @@ REGISTRY: Dict[str, KernelSpec] = {
                 {"S": 36, "H": 64, "R": 20},
             ),
             # the tiered-insert head merge (H=256 default head tier, a
-            # routed run of B lanes)
-            tuning_shapes=({"S": 64, "H": 256, "R": 57},),
+            # routed run of B lanes: 57 in Table 3, 128 in PHOLD's hold)
+            tuning_shapes=({"S": 64, "H": 256, "R": 57},
+                           {"S": 64, "H": 256, "R": 128}),
             make_inputs=_mk_windowed_merge, cost_model=_cost_windowed_merge,
         ),
         _spec(

@@ -195,10 +195,11 @@ def tune_kernel(name: str, coords: Mapping[str, object], *,
     }
     best = min(timings, key=timings.get)
     winner = best
-    if spec.default in timings and (
-            timings[spec.default] < timings[best] * MIN_SPEEDUP
-            or timings[spec.default] - timings[best] < MIN_GAIN_US):
-        winner = spec.default
+    default = spec.default_for()
+    if default in timings and (
+            timings[default] < timings[best] * MIN_SPEEDUP
+            or timings[default] - timings[best] < MIN_GAIN_US):
+        winner = default
     return {"arm": winner, "us": round(timings[winner], 3),
             "best": best,
             "timings": {k: round(v, 3) for k, v in timings.items()}}

@@ -38,6 +38,8 @@ def test_all_arms_bit_identical(name):
     spec = REG.REGISTRY[name]
     arms = [a.name for a in spec.available_arms()]
     assert spec.default in arms  # the fallback must always be runnable
+    for backend in ("cpu", "gpu", "tpu"):  # and so must every default
+        assert spec.arm(spec.default_for(backend)).kind == "jnp"
     for coords in spec.validation_shapes:
         base = _run_arm(spec, coords, arms[0])
         for arm in arms[1:]:
@@ -79,6 +81,41 @@ def test_resolve_precedence_explicit_then_forced_then_default():
         assert REG.resolve("segment_min_into",
                            dict(segmin.validation_shapes[0])) == \
             segmin.default
+
+
+def test_windowed_merge_default_is_sort_on_tpu_only(tmp_path, monkeypatch):
+    """With nothing explicit, forced or tuned, `windowed_merge` resolves to
+    the gather-free `sort` arm on the TPU and to `rank` elsewhere; an
+    explicit, forced or tuned arm still wins on the TPU."""
+    import jax
+
+    spec = REG.REGISTRY["windowed_merge"]
+    coords = dict(spec.tuning_shapes[0])
+    assert [spec.default_for(b) for b in ("tpu", "cpu", "gpu")] == \
+        ["sort", "rank", "rank"]
+    path = tmp_path / "kernels_tpu.json"
+    monkeypatch.setenv(tuning.CACHE_ENV, str(path))
+    tuning.invalidate_cache()
+    try:
+        assert REG.resolve("windowed_merge", coords) == "rank"  # the CPU
+        monkeypatch.setattr(REG.jax, "default_backend", lambda: "tpu")
+        tuning.invalidate_cache()
+        assert REG.resolve("windowed_merge", coords) == "sort"
+        assert ("windowed_merge", REG.sig(coords), "sort", "default") in \
+            REG.RESOLVED
+        assert REG.resolve("windowed_merge", coords, arm="rank") == "rank"
+        with REG.force_arms({"windowed_merge": "ref"}):
+            assert REG.resolve("windowed_merge", coords) == "ref"
+        path.write_text(json.dumps(
+            {"schema": 1, "backend": "tpu", "jax": jax.__version__,
+             "records": {tuning.TuningCache.key("windowed_merge",
+                                                REG.sig(coords)):
+                         {"arm": "compiled@rows_per_block=8", "us": 1.0}}}))
+        tuning.invalidate_cache()
+        assert REG.resolve("windowed_merge", coords) == \
+            "compiled@rows_per_block=8"
+    finally:
+        tuning.invalidate_cache()
 
 
 def test_interpret_arms_unavailable_on_tpu():

@@ -110,15 +110,17 @@ def test_merge_against_local_semantics():
     np.testing.assert_array_equal(np.asarray(nk), np.asarray(mk))
 
 
-@pytest.mark.parametrize("arm", PALLAS_ARMS)
+@pytest.mark.parametrize("arm", PALLAS_ARMS + ("sort",))
 @pytest.mark.parametrize(
     "S,H,R", [(4, 64, 16), (8, 128, 128), (2, 256, 7), (1, 64, 1),
-              (6, 100, 60), (3, 8, 8)]
+              (6, 100, 60), (3, 8, 8),
+              (64, 256, 57), (64, 256, 128)]  # Table 3's and the hold's
 )
 def test_windowed_merge_exact(S, H, R, arm):
-    """The windowed-merge kernel (full H+R window, nothing dropped) must be
-    bit-identical to BOTH the lexicographic reference and the
-    positional-stable rank merge in local.merge_head_run."""
+    """The windowed-merge kernel and the gather-free sort arm (full H+R
+    window, nothing dropped) must be bit-identical to BOTH the
+    lexicographic reference and the positional-stable rank merge in
+    local.merge_head_run."""
     from repro.core.pqueue.local import merge_head_run
     from repro.kernels.ops import windowed_merge
 
@@ -163,3 +165,43 @@ def test_tiered_insert_kernel_path_matches():
         __import__("jax").tree.leaves(st_ref), __import__("jax").tree.leaves(st_ker)
     ):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_tiered_insert_sort_arm_matches_rank():
+    """Several tiered inserts through the sort arm leave the same state,
+    byte for byte, as through the rank arm: a first batch that spills past
+    the head width, tail appends, and a step whose bucket compaction
+    fires."""
+    import jax
+
+    from repro.core.pqueue import ops as O
+    from repro.core.pqueue.state import make_state
+    from repro.kernels import registry as REG
+
+    rng = np.random.default_rng(11)
+    batches = [(jnp.asarray(rng.integers(0, 50, 256), jnp.int32),  # ties
+                jnp.asarray(rng.integers(0, 99, 256), jnp.int32))
+               for _ in range(6)]
+
+    def run(arm):
+        states = []
+        st = make_state(4, 16 + 512, head_width=16)
+        with REG.force_arms({"windowed_merge": arm}):
+            for keys, vals in batches:
+                st, dropped = O.insert(st, keys, vals)
+                assert not np.any(np.asarray(dropped))
+                states.append(st)
+        return states
+
+    ranked, sorted_ = run("rank"), run("sort")
+    assert any(k[0] == "windowed_merge" and k[2:] == ("sort", "forced")
+               for k in REG.RESOLVED)
+    first = ranked[0]
+    assert np.all(np.asarray(first.head_size) == 16)  # spilled past H
+    assert np.all(np.asarray(first.tail_size) > 0)
+    # a compaction sorts the whole tail: tail_sorted jumps to its size
+    sorted_counts = [np.asarray(s.tail_sorted) for s in ranked]
+    assert any(np.any(b > a) for a, b in zip(sorted_counts, sorted_counts[1:]))
+    for a, b in zip(ranked, sorted_):
+        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
