@@ -15,9 +15,16 @@ A run:
    persistent cache) before the clock starts;
 2. the timed window: closed-loop windows for `seconds`;
 3. with `trace`, a further traced segment of `TRACE_SECONDS` under the
-   profiler, reduced by `bench/trace_reduce.py`;
+   profiler; its `.xplane.pb` is parsed once and reduced both by opcode
+   (`bench/trace_reduce.py`) and by the program's named scopes
+   (`bench/trace_scopes.py`);
 4. the check (`bench/check.py`) of every window run, after the program's
    state is read back and freed.
+
+A reader sees the run through `RunView`: every `SmartPQStats` counter,
+the set-up by phase, device time by opcode and by scope, and the cell's
+config and traffic, so that a metric of what a later configuration adds
+is a new reader file and no edit here.
 """
 
 from __future__ import annotations
@@ -31,7 +38,7 @@ import sys
 import tempfile
 import time
 from pathlib import Path
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -45,6 +52,10 @@ ROOT = BENCH.parent
 TRACE_SECONDS = 3.0
 HOST_SPANS = ("generate", "dispatch", "readback")
 WINDOW_SPAN = "bench.window"
+# set-up, in order: process start to the first step of set-up (imports, the
+# compile cache, the TPU client, the cell's files); the queue with its
+# decision tree trained; the prefill; the two warm-up windows
+SETUP_PHASES = ("startup", "tree", "prefill", "warmup")
 _COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                    "/jax/core/compile/backend_compile_duration")
 
@@ -77,7 +88,7 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     cfg = {c["name"]: c for c in spec["configs"]}[cell["config"]]
     config = json.loads((root / cfg["file"]).read_text())
     traffic = json.loads(
-        (BENCH / "traffic" / f"{cell['traffic']}.json").read_text())
+        (root / "bench" / "traffic" / f"{cell['traffic']}.json").read_text())
     return Cell(
         name=workload, chips=int(cell["chips"]), config=config,
         traffic=traffic,
@@ -86,14 +97,31 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     )
 
 
-def metric_reader(name: str):
+def metric_reader(name: str, root: Path = ROOT):
     """`read(view)` of bench/metrics/<name>.py."""
-    path = BENCH / "metrics" / f"{name}.py"
+    path = root / "bench" / "metrics" / f"{name}.py"
     spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
                                                   path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def use_compile_cache() -> None:
+    """The persistent compilation cache every benchmark command uses: the
+    program's own directory (`repro.utils.compile_cache`), every program
+    cached however quickly it compiled, so that only a cell's first run
+    in a checkout compiles, and keyed with the programs' debug info. The
+    cache keys a program without it by default, so an executable compiled
+    from a tree without the program's named scopes would serve a tree with
+    them, and the HLO it embeds in a trace would name no scope."""
+    from repro.utils.compile_cache import use_compile_cache as use
+
+    use()
+    import jax
+
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def device_info(chips: int, require_tpu: bool = True) -> dict:
@@ -178,13 +206,13 @@ def held_keys(state) -> np.ndarray:
 
 
 def stats_of(carry) -> dict:
+    """Every field of the carry's `SmartPQStats`, by its `_fields`: a
+    scalar as an int, an array as an int64 array."""
     import jax
 
     s = jax.device_get(carry.stats)
-    return {"step": int(s.step), "transitions": int(s.transitions),
-            "eliminated": int(s.eliminated), "rejected": int(s.rejected),
-            "head_refills": int(s.head_refills),
-            "mode_steps": np.asarray(s.mode_steps, np.int64)}
+    return {k: int(v) if np.ndim(v) == 0 else np.asarray(v, np.int64)
+            for k, v in zip(s._fields, s)}
 
 
 @dataclasses.dataclass
@@ -192,11 +220,21 @@ class RunView:
     """What a per-layer reader may read."""
 
     steps: int
-    counters: dict  # deltas over the timed window
+    counters: dict  # every SmartPQStats field, its delta over the window
     relaxed_modes: List[int]
     ops_completed: int
     dispatch_s: List[float]
-    trace: Optional[object]  # trace_reduce.Reduction, with --trace 1
+    setup: Dict[str, float]  # set-up phase -> host seconds (SETUP_PHASES)
+    config: dict  # the configuration the run used, overrides merged
+    traffic: dict
+    device_kind: str
+    # with --trace 1: trace_reduce.Reduction of the traced segment, its ops
+    # with their scopes (trace_scopes.Attributed), own seconds by scope
+    # (trace_scopes.by_scope), and what reducing the trace cost
+    trace: Optional[object] = None
+    scope_ops: list = dataclasses.field(default_factory=list)
+    scopes: Dict[str, float] = dataclasses.field(default_factory=dict)
+    trace_cost: Dict[str, float] = dataclasses.field(default_factory=dict)
 
 
 def _spin(loop, carry, w0: int, seconds: float, annotate=None):
@@ -232,6 +270,30 @@ def _spin(loop, carry, w0: int, seconds: float, annotate=None):
             return carry, records, times, disp, starts, first, te
 
 
+def reduce_trace(path: str) -> dict:
+    """One parse of a `.xplane.pb`, reduced by opcode and by scope: the
+    trace fields of `RunView`."""
+    from jax.profiler import ProfileData
+
+    from bench import trace_reduce, trace_scopes
+
+    t0 = time.perf_counter()
+    with open(path, "rb") as f:
+        xspace = f.read()
+    planes = list(ProfileData.from_serialized_xspace(xspace).planes)
+    t1 = time.perf_counter()
+    red = trace_reduce.reduce_profile(planes, WINDOW_SPAN, HOST_SPANS)
+    t2 = time.perf_counter()
+    ops = trace_scopes.attribute(planes, trace_scopes.hlo_op_names(xspace),
+                                 WINDOW_SPAN)
+    scopes = trace_scopes.by_scope(ops)
+    t3 = time.perf_counter()
+    cost = {"parse_s": t1 - t0, "by_opcode_s": t2 - t1,
+            "by_scope_s": t3 - t2, "xplane_bytes": len(xspace)}
+    return {"trace": red, "scope_ops": ops, "scopes": scopes,
+            "trace_cost": cost}
+
+
 def _traced(loop, carry, w0: int):
     import jax
 
@@ -245,25 +307,33 @@ def _traced(loop, carry, w0: int):
             carry, records, *_ = _spin(loop, carry, w0, TRACE_SECONDS,
                                        jax.profiler.TraceAnnotation)
         jax.profiler.stop_trace()
-        red = trace_reduce.reduce_dir(d, WINDOW_SPAN, HOST_SPANS)
-    return carry, records, red
+        reduced = reduce_trace(trace_reduce.xplane_in(d))
+    return carry, records, reduced
 
 
-def _set_up(cell: Cell, config: dict, seed: int, tree):
+def _set_up(cell: Cell, config: dict, seed: int, tree, phases: dict):
     """Queue, loop and prefilled carry; then the window program warmed up
     on a fresh queue of the same shapes (the rng wrap and the readback
     too, twice), with inputs from a stream of their own so that the timed
-    windows see the same traffic however set-up runs."""
+    windows see the same traffic however set-up runs. Adds the host
+    seconds of the tree, prefill and warm-up phases to `phases`."""
+    t0 = time.perf_counter()
     pq = build_queue(config, tree)
+    t1 = time.perf_counter()
     loop = LOOPS[cell.traffic["loop"]](pq, config, cell.traffic, seed)
     keys = G.prefill_keys(config["prefill"], seed)
     to_fill, in_service = loop.split_prefill(keys)
-    carry = loop.start(
-        prefill_carry(pq, to_fill, config["prefill"]["batch"]), in_service)
+    t2 = time.perf_counter()
+    filled = prefill_carry(pq, to_fill, config["prefill"]["batch"])
+    t3 = time.perf_counter()
+    carry = loop.start(filled, in_service)
     scratch = loop.start(pq.init(), in_service)
+    t4 = time.perf_counter()
     for w in range(2):
         scratch, out = loop.dispatch(scratch, loop.inputs(w, G.STREAM_WARMUP))
         loop.readback(out)
+    t5 = time.perf_counter()
+    phases.update(tree=t1 - t0, prefill=t3 - t2, warmup=t5 - t4)
     return loop, carry, to_fill, in_service
 
 
@@ -282,15 +352,20 @@ def _check(config: dict, loop, records, to_fill, in_service, held,
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              t_start: Optional[float] = None, require_tpu: bool = True,
              overrides: Optional[dict] = None, tree=None,
-             root: Path = ROOT, log=None) -> dict:
-    """One run of a cell; returns the result line (a dict)."""
+             root: Path = ROOT, log=None):
+    """One run of a cell: (the result line, the `RunView` its per-layer
+    readers read)."""
     t_start = time.perf_counter() if t_start is None else t_start
     log = log or (lambda msg: print(msg, file=sys.stderr, flush=True))
     cell = load_cell(workload, root)
     config = {**cell.config, **(overrides or {})}
     device = device_info(cell.chips, require_tpu)
 
-    loop, carry, to_fill, in_service = _set_up(cell, config, seed, tree)
+    t_setup = time.perf_counter()
+    phases = {"startup": t_setup - t_start}
+    loop, carry, to_fill, in_service = _set_up(cell, config, seed, tree,
+                                               phases)
+    phases = {k: phases[k] for k in SETUP_PHASES}
     size0 = int(loop.pq_carry(carry).state.total_size)
     stats0 = stats_of(loop.pq_carry(carry))
     # Set-up leaves some 10^5 long-lived Python objects (traced programs,
@@ -310,11 +385,20 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     size1 = int(loop.pq_carry(carry).state.total_size)
     n_main = len(records)
 
-    red = None
+    log("[bench] setup_phases " + " ".join(
+        f"{k}={v:.3f}" for k, v in phases.items())
+        + f" rest={setup_s - sum(phases.values()):.3f}")
+
+    traced = {}
     if trace:
-        carry, more, red = _traced(loop, carry, n_main)
+        t_trace = time.perf_counter()
+        carry, more, traced = _traced(loop, carry, n_main)
         records = records + more
+        red = traced["trace"]
         device.update(busy_s=red.busy_s, window_s=red.window_s)
+        log(f"[bench] traced_s={time.perf_counter() - t_trace:.3f} "
+            + " ".join(f"{k}={v:.3f}" if isinstance(v, float) else
+                       f"{k}={v}" for k, v in traced["trace_cost"].items()))
     gc.unfreeze()
     state = loop.pq_carry(carry).state
     size_end = int(state.total_size)
@@ -340,16 +424,18 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         0, size0 + ins_main - popped_main - size1)
     counters = {k: stats1[k] - stats0[k] for k in stats0}
     failed = drops + counters["rejected"] + checker.empty_deletes
+    view = RunView(
+        steps=counters["step"], counters=counters,
+        relaxed_modes=[i for i, w in enumerate(checker.windows)
+                       if w is not None],
+        ops_completed=completed, dispatch_s=disp, setup=phases,
+        config=config, traffic=cell.traffic, device_kind=device["kind"],
+        **traced)
 
     if trace:
-        view = RunView(
-            steps=counters["step"], counters=counters,
-            relaxed_modes=[i for i, w in enumerate(checker.windows)
-                           if w is not None],
-            ops_completed=completed, dispatch_s=disp, trace=red)
         metrics = {}
         for m in cell.per_layer:
-            v = metric_reader(m["name"])(view)
+            v = metric_reader(m["name"], root)(view)
             if v is not None:
                 metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
     else:
@@ -361,7 +447,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             "attempted": ins_all + del_all, "failed": int(failed),
             "metrics": metrics, "device": device}
     if trace:
-        line["breakdown"] = red.breakdown()
+        line["breakdown"] = view.trace.breakdown()
     line["checks"] = checks
     med = statistics.median(times)
     slow = sorted(range(len(times)), key=lambda i: -times[i])[:3]
@@ -377,4 +463,4 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         f"p95={float(np.percentile(times, 95)) * 1e3:.3f} "
         f"ops_completed={completed} drops={drops} "
         f"empty_deletes={checker.empty_deletes}")
-    return line
+    return line, view

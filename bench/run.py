@@ -34,20 +34,13 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
 
-    from repro.utils.compile_cache import use_compile_cache
+    from bench.harness import NoChip, run_cell, use_compile_cache
 
     use_compile_cache()
-    import jax
-
-    # cache every program, however quickly it compiled, so that only a
-    # cell's first run in a checkout compiles
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-
-    from bench.harness import NoChip, run_cell
 
     try:
-        line = run_cell(args.workload, args.seed, args.seconds,
-                        bool(args.trace), t_start=T_START)
+        line, _ = run_cell(args.workload, args.seed, args.seconds,
+                           bool(args.trace), t_start=T_START)
     except NoChip as e:
         print(f"bench: {e}", file=sys.stderr, flush=True)
         return 3

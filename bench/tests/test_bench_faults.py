@@ -80,6 +80,6 @@ def test_a_broken_step_is_not_correct(tree, monkeypatch, workload, fault):
 
     monkeypatch.setattr(SmartPQ, "step", FAULTS[fault](SmartPQ.step))
     over = {**_tiny(workload), "hold_mean": 1024, "hold_max": 1 << 16}
-    line = run_cell(workload, 2**31 + 5, 0.3, False, require_tpu=False,
-                    overrides=over, tree=tree, log=lambda msg: None)
+    line, _ = run_cell(workload, 2**31 + 5, 0.3, False, require_tpu=False,
+                       overrides=over, tree=tree, log=lambda msg: None)
     assert not line["correct"], line["checks"]

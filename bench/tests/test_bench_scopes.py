@@ -20,6 +20,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
+from bench import harness as H  # noqa: E402
 from bench import trace_reduce as R  # noqa: E402
 from bench import trace_scopes as S  # noqa: E402
 
@@ -30,10 +31,11 @@ SPANS = ("generate", "dispatch", "readback")
 
 
 def _reduce(path):
-    data = path.read_bytes()
-    ops = S.reduce_bytes(data, "bench.window")
-    return (S.hlo_op_names(data), ops, S.by_scope(ops),
-            R.reduce_file(str(path), "bench.window", SPANS))
+    """The harness's one parse of the trace, beside an opcode reduction
+    of its own parse."""
+    one = H.reduce_trace(str(path))
+    return (S.hlo_op_names(path.read_bytes()), one["scope_ops"],
+            one["scopes"], R.reduce_file(str(path), "bench.window", SPANS))
 
 
 @pytest.fixture(scope="module")
@@ -193,6 +195,63 @@ def test_the_wire_reader():
     assert S._text(msg, fields[1][1]) == "hi"
     assert S._ints(msg, fields[2][1]) == [3, 270]
     assert S._ints(msg, 7) == [7]
+
+
+SCOPE_READERS = ("decide_busy_pct", "eliminate_busy_pct",
+                 "insert_busy_pct", "refill_busy_pct", "compact_busy_pct",
+                 "schedule_busy_pct", "unscoped_busy_pct")
+
+
+def _view(path):
+    """A `RunView` with the trace fields the harness's traced path fills."""
+    return H.RunView(steps=1, counters={}, relaxed_modes=[],
+                     ops_completed=1, dispatch_s=[], setup={}, config={},
+                     traffic={}, device_kind="TPU v5 lite",
+                     **H.reduce_trace(str(path)))
+
+
+def test_one_parse_feeds_the_scope_readers():
+    """The seven scope readers over the harness's one parse of a scoped
+    trace partition busy time, and that busy time is `trace_reduce`'s
+    from the same bytes."""
+    view = _view(SCOPED)
+    alone = R.reduce_file(str(SCOPED), "bench.window", SPANS)
+    assert view.trace.busy_s == alone.busy_s
+    assert view.trace.by_opcode == alone.by_opcode
+    values = {n: H.metric_reader(n)(view) for n in SCOPE_READERS}
+    assert all(v is not None and v >= 0 for v in values.values())
+    # within 1e-6 of 100 relative; in seconds, within the trace's rounding
+    # of event times to whole nanoseconds
+    total = sum(values.values())
+    assert total == pytest.approx(100.0, rel=1e-6)
+    assert abs(total - 100.0) / 100.0 * view.trace.busy_s <= 2e-9
+    assert values["insert_busy_pct"] > 0 and values["compact_busy_pct"] > 0
+    assert view.trace_cost["xplane_bytes"] == SCOPED.stat().st_size
+
+
+def test_every_scope_of_the_trace_reaches_the_view():
+    """A `pq.*` or `kernel.*` scope the program adds is in `RunView.scopes`
+    with no edit to the harness."""
+    view = _view(SCOPED)
+    named = {S.scopes_of(o[3])[i] for o in view.scope_ops for i in (0, 1)}
+    named.discard(None)
+    assert named and named <= set(view.scopes)
+    assert "kernel.windowed_merge.rank" in view.scopes
+
+
+@pytest.mark.parametrize("name", SCOPE_READERS)
+def test_an_unscoped_trace_gives_no_scope_share(name):
+    view = _view(UNSCOPED)
+    assert view.trace.busy_s > 0
+    assert H.metric_reader(name)(view) is None
+
+
+@pytest.mark.parametrize("name", SCOPE_READERS)
+def test_a_run_without_a_trace_gives_no_scope_share(name):
+    view = H.RunView(steps=1, counters={}, relaxed_modes=[],
+                     ops_completed=1, dispatch_s=[], setup={}, config={},
+                     traffic={}, device_kind="cpu")
+    assert H.metric_reader(name)(view) is None
 
 
 def test_command_refuses_to_run_without_a_tpu():

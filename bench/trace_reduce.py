@@ -201,8 +201,3 @@ def reduce_file(path: str, window_span: str,
 
     return reduce_profile(ProfileData.from_file(path).planes, window_span,
                           host_spans)
-
-
-def reduce_dir(directory: str, window_span: str,
-               host_spans: Sequence[str]) -> Reduction:
-    return reduce_file(xplane_in(directory), window_span, host_spans)

@@ -24,15 +24,19 @@ the serialized `HloProto` under the stat `Hlo Proto`. This module
 Like `trace_reduce`, it reads only what the profiler wrote and imports
 nothing from the program.
 
-Run on the chip, it measures one cell's traced segment by scope:
+The harness reduces each traced segment with it
+(`harness.reduce_trace`), and the readers of `bench/metrics/` take their
+shares through `share`. Run on the chip, it prints one cell's traced
+segment by scope and by instruction:
 
     python3 bench/trace_scopes.py --workload pq1m.table3 --seed 7 --seconds 51
 
-That is `bench/run.py`'s set-up and `--seconds` of untraced windows, then a
-traced segment of `harness.TRACE_SECONDS`. The last line of stdout is one
-JSON object: seconds by scope, the layer shares of busy time, the heaviest
-instructions (program, name, opcode, executions, seconds, op_name), and how
-long each reduction of the trace took.
+That is `bench/run.py --trace 1` (`harness.run_cell`): set-up, `--seconds` of
+untraced windows, a traced segment of `harness.TRACE_SECONDS` and the
+check. The last line of stdout is one JSON object: seconds by scope, the
+layer shares of busy time, the heaviest instructions (program, name,
+opcode, executions, seconds, op_name), the set-up by phase, and how long
+each reduction of the trace took.
 """
 
 from __future__ import annotations
@@ -344,11 +348,13 @@ def shares(scopes: Dict[str, float], busy_s: float) -> Dict[str, float]:
             for name, prefixes in SHARES.items()}
 
 
-def reduce_bytes(xspace: bytes, window_span: str) -> List[Attributed]:
-    from jax.profiler import ProfileData
-
-    return attribute(ProfileData.from_serialized_xspace(xspace).planes,
-                     hlo_op_names(xspace), window_span)
+def share(view, name: str) -> Optional[float]:
+    """`SHARES[name]` of a run's traced segment, for a reader of
+    `bench/metrics/`: None without a trace, or where the trace names no
+    `pq.*` scope."""
+    if view.trace is None:
+        return None
+    return shares(view.scopes, view.trace.busy_s).get(name)
 
 
 # ---------------------------------------------------------------------------
@@ -357,64 +363,37 @@ def reduce_bytes(xspace: bytes, window_span: str) -> List[Attributed]:
 
 
 def measure(workload: str, seed: int, seconds: float) -> dict:
-    import gc
+    """A traced benchmark run of the cell (`harness.run_cell`), printed by
+    scope and by instruction."""
     import json
-    import tempfile
-    import time
-
-    import jax
 
     from bench import harness as H
-    from bench import trace_reduce
 
-    cell = H.load_cell(workload)
-    device = H.device_info(cell.chips)
-    loop, carry, *_ = H._set_up(cell, cell.config, seed, None)
-    gc.collect()
-    gc.freeze()
-    carry, records, *_ = H._spin(loop, carry, 0, seconds)
-
-    opts = jax.profiler.ProfileOptions()
-    opts.python_tracer_level = 0
-    with tempfile.TemporaryDirectory() as d:
-        jax.profiler.start_trace(d, profiler_options=opts)
-        with jax.profiler.TraceAnnotation(H.WINDOW_SPAN):
-            carry, traced, *_ = H._spin(loop, carry, len(records),
-                                        H.TRACE_SECONDS,
-                                        jax.profiler.TraceAnnotation)
-        jax.profiler.stop_trace()
-        path = trace_reduce.xplane_in(d)
-        t0 = time.perf_counter()
-        red = trace_reduce.reduce_file(path, H.WINDOW_SPAN, H.HOST_SPANS)
-        t1 = time.perf_counter()
-        with open(path, "rb") as f:
-            xspace = f.read()
-        ops = reduce_bytes(xspace, H.WINDOW_SPAN)
-        scopes = by_scope(ops)
-        t2 = time.perf_counter()
-    gc.unfreeze()
+    line, view = H.run_cell(workload, seed, seconds, True)
+    red = view.trace
     heavy: Dict[tuple, list] = {}  # (program, instr, opcode, op_name) ->
     # [executions, seconds]
-    for program, instr, opcode, op_name, own in ops:
+    for program, instr, opcode, op_name, own in view.scope_ops:
         h = heavy.setdefault((program.split("(")[0], instr, opcode, op_name),
                              [0, 0.0])
         h[0] += 1
         h[1] += own
-    line = {
-        "workload": workload, "seed": seed, "device": device,
-        "windows": len(records), "traced_windows": len(traced),
+    out = {
+        "workload": workload, "seed": seed, "correct": line["correct"],
+        "device": line["device"], "windows": len(view.dispatch_s),
+        "setup": view.setup,
         "busy_s": red.busy_s, "window_s": red.window_s,
         "idle_pct": red.idle_pct,
-        "by_scope": dict(sorted(scopes.items(), key=lambda kv: -kv[1])),
-        "shares_pct": shares(scopes, red.busy_s),
-        "layer_sum_pct": 100.0 * sum(layers(scopes).values()) / red.busy_s,
+        "by_scope": dict(sorted(view.scopes.items(), key=lambda kv: -kv[1])),
+        "shares_pct": shares(view.scopes, red.busy_s),
+        "layer_sum_pct": 100.0 * sum(layers(view.scopes).values())
+        / red.busy_s,
         "heaviest_ops": [[*k[:3], n, t, k[3]] for k, (n, t) in sorted(
             heavy.items(), key=lambda kv: -kv[1][1])[:25]],
-        "reduce_s": {"by_opcode": t1 - t0, "by_scope": t2 - t1},
-        "xplane_bytes": len(xspace),
+        "trace_cost": view.trace_cost,
     }
-    print(json.dumps(line), flush=True)
-    return line
+    print(json.dumps(out), flush=True)
+    return out
 
 
 def main(argv=None) -> int:
@@ -427,17 +406,9 @@ def main(argv=None) -> int:
                     help="untraced windows before the traced segment")
     args = ap.parse_args(argv)
 
-    from repro.utils.compile_cache import use_compile_cache
+    from bench.harness import NoChip, use_compile_cache
 
     use_compile_cache()
-    import jax
-
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    # The persistent cache keys a program without its debug info, so an
-    # executable compiled from a tree without the scopes would serve this
-    # one too, and the HLO it embeds in the trace would name no scope.
-    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
-    from bench.harness import NoChip
 
     try:
         measure(args.workload, args.seed, args.seconds)
